@@ -48,11 +48,13 @@ import time
 from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.cluster.store import ShardedStore
 from repro.configs.paper_search import SearchConfig
 from repro.core.engine import SearchResult, _merge_results
+from repro.distributed.meshctx import single_device_ctx
 from repro.obs import NULL_SPAN, Obs, default_obs
 from repro.serve.api import (Query, QueryOptions, QueryStats, SearchResponse,
                              coerce_request, truncate_k)
@@ -196,6 +198,11 @@ class ShardRouter:
         self.store = store
         self.cfg = cfg
         self.backend = backend
+        # one chip per shard replica (DESIGN.md §5.2): replica r of shard
+        # s runs on devices[(s + r) % n], so the primaries spread over
+        # every chip and a shard's replicas never share one (while
+        # replicas <= chips)
+        self.devices = jax.local_devices()
         self.use_filter = use_filter
         self.prefetch_depth = prefetch_depth
         # approximate-tier defaults for every shard session (§15): each
@@ -295,6 +302,7 @@ class ShardRouter:
             if self._sessions[shard][replica] is None:
                 sess = FlashSearchSession(
                     self.store.store(shard, replica), self.cfg,
+                    ctx=single_device_ctx(self.device_of(shard, replica)),
                     backend=self.backend, use_filter=self.use_filter,
                     prefetch_depth=self.prefetch_depth,
                     slab_cache=self.slab_cache,
@@ -309,6 +317,10 @@ class ShardRouter:
                     sess.enable_ingest(**self._ingest_knobs)
                 self._sessions[shard][replica] = sess
             return self._sessions[shard][replica]
+
+    def device_of(self, shard: int, replica: int):
+        """The chip that serves replica ``replica`` of ``shard``."""
+        return self.devices[(shard + replica) % len(self.devices)]
 
     # -- live ingestion (DESIGN.md §6.3) -------------------------------
     def enable_ingest(self, **knobs):

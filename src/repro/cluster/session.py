@@ -44,7 +44,8 @@ class FlashClusterSession(ServingSessionMixin):
         exact by default), ``memo_entries`` sizes the cluster-shared
         recurrent-query memo cache (0 = off); per-query
         ``QueryOptions.mode/recall_target/candidates`` overrides ride
-        the scatter to every shard."""
+        the scatter to every shard. Shard replicas are spread over every
+        local device (``ShardRouter.device_of``)."""
         if isinstance(store, str):
             store = ShardedStore.open(store)
         if store.vocab_size > cfg.vocab_size:

@@ -104,7 +104,6 @@ class PatternSearchEngine:
             raise ValueError(
                 f"corpus word ids reach {int(corpus.ids.max())} but "
                 f"cfg.vocab_size={cfg.vocab_size}")
-        ndev = ctx.mesh.size
         rows = ctx.dp_size
         n = -(-corpus.n_docs // rows) * rows
         corpus = corpus.pad_docs_to(n)
@@ -112,6 +111,10 @@ class PatternSearchEngine:
         self.tiling = tiling if tiling is not None else FixedTiling(
             cfg.block_docs, cfg.block_query)
         self.f_tiles: Optional[jax.Array] = None
+        # the device a single-device mesh lives on: fused tiles are
+        # placed there explicitly, never on JAX's default device
+        self._device = (ctx.mesh.devices.flat[0] if ctx.mesh.size == 1
+                        else None)
         if backend == "pallas_fused":
             # the fused kernel scores a single device's packed tiles;
             # sharded meshes keep the staged per-device kernels
@@ -122,13 +125,11 @@ class PatternSearchEngine:
                     " devices — use 'pallas' or 'jnp' there")
             self._block_docs = self.tiling.doc_tile(
                 nnz_pad=cfg.nnz_pad, n_docs=corpus.n_docs)
-            tiles, _, self._resident_trunc = kfused.tile_stream(
-                kfused.corpus_to_stream(corpus),
-                block_docs=self._block_docs, nnz_pad=cfg.nnz_pad,
-                pad_docs_to=corpus.n_docs)
-            # no host ELL staging, no per-array uploads: one uint32
-            # tile matrix is the whole resident corpus
-            self.f_tiles = jax.device_put(tiles)
+            # no host ELL staging, no per-array uploads: one packed
+            # tile array is the whole resident corpus
+            slab, _, self._resident_trunc = self._device_tiles(
+                kfused.corpus_to_stream(corpus), corpus.n_docs)
+            self.f_tiles = slab.tiles
             self.d_ids = self.d_vals = None
             self.d_norms = self.d_docids = None
         else:
@@ -154,50 +155,19 @@ class PatternSearchEngine:
         # n_docs) key; _trace_keys is appended at *trace* time inside the
         # jitted body, so it counts real recompiles, not call shapes
         self._trace_keys: list = []
-        self._search_fn = (self._build_fused() if backend == "pallas_fused"
-                           else self._build(ndev))
-
-    # ------------------------------------------------------------------
-    def _build(self, ndev: int):
-        cfg, ctx, backend = self.cfg, self.ctx, self.backend
-        tp = ctx.tp_axis
-        dp = ctx.dp_axes
-
-        def local_score(ids, vals, norms, docids, q_ids, q_vals, q_norms):
-            """Per-device: score local corpus shard x local query columns."""
-            corr = kops.correlate(
-                ids, vals, q_ids, q_vals, backend=backend,
-                vocab_size=cfg.vocab_size, block_docs=cfg.block_docs,
-                block_query=cfg.block_query)
-            cos = kops.cosine_scores(corr, norms, q_norms)
-            v, i = topk_lib.local_topk(cos, docids, cfg.top_k)
-            # reduce across the corpus-shard (K) axes — paper's report path
-            for ax in dp:
-                v, i = topk_lib.tree_topk(v, i, cfg.top_k, ax)
-            return v, i
-
-        qcols_spec = P(None, tp)  # L value-columns over the model axis
-        trace_keys = self._trace_keys
         # registry handle resolved once: the jitted body's python side
         # effect stays one list append + one counter inc per real trace
-        trace_counter = self.obs.registry.counter("engine_compile_traces")
+        self._trace_counter = self.obs.registry.counter(
+            "engine_compile_traces")
+        self._search_fn = (self._build_fused() if backend == "pallas_fused"
+                           else slab_program(cfg, ctx, backend,
+                                             self._on_trace))
 
-        @jax.jit
-        def search(ids, vals, norms, docids, q_ids, q_vals, q_norms):
-            # python side effect: runs once per trace (i.e. per compiled
-            # program), never on a jit cache hit
-            trace_keys.append((q_norms.shape[0], q_ids.shape[0],
-                               ids.shape[0]))
-            trace_counter.inc()
-            f = shard_map(
-                local_score, mesh=ctx.mesh,
-                in_specs=(P(dp, None), P(dp, None), P(dp), P(dp),
-                          P(None), qcols_spec, P(tp)),
-                out_specs=(P(tp, None), P(tp, None)),
-                check_vma=False)
-            return f(ids, vals, norms, docids, q_ids, q_vals, q_norms)
-
-        return search
+    def _on_trace(self, key):
+        """Compile-cache bookkeeping, called once per trace of a jitted
+        program (never on a jit cache hit)."""
+        self._trace_keys.append(key)
+        self._trace_counter.inc()
 
     def _build_fused(self):
         """The fused path's one dispatch: packed tiles + merged stream ->
@@ -206,14 +176,11 @@ class PatternSearchEngine:
         adds no program shapes beyond the bucket's."""
         cfg = self.cfg
         bd = self._block_docs
-        trace_keys = self._trace_keys
-        trace_counter = self.obs.registry.counter("engine_compile_traces")
+        on_trace = self._on_trace
 
         @functools.partial(jax.jit, static_argnames=("block_query",))
         def search(tiles, q_ids, q_vals, q_norms, block_query):
-            trace_keys.append((q_norms.shape[0], q_ids.shape[0],
-                               tiles.shape[0] * bd))
-            trace_counter.inc()
+            on_trace((q_norms.shape[0], q_ids.shape[0], tiles.shape[0] * bd))
             return kops.fused_topk(tiles, q_ids, q_vals, q_norms,
                                    k=cfg.top_k, block_docs=bd,
                                    block_query=block_query)
@@ -264,18 +231,13 @@ class PatternSearchEngine:
         no wrapping, no shim warning (see serve/search_service.py)."""
         return self._search_arrays(*query.rows())
 
-    def _search_arrays(self, q_ids: np.ndarray,
-                       q_vals: np.ndarray) -> SearchResult:
-        """q_ids/q_vals: [L, Qn] (pad < 0). L is padded to its compile
-        bucket (next power-of-two multiple of the model-axis size — the
-        paper's L query batch, bucketed so the serving layer's variable
-        batches reuse cached programs)."""
+    def _program_args(self, q_ids: np.ndarray, q_vals: np.ndarray):
+        """q_ids/q_vals: [L, Qn] (pad < 0) -> (args, kwargs) of this
+        engine's jitted program. L is padded to its compile bucket (next
+        power-of-two multiple of the model-axis size — the paper's L
+        query batch, bucketed so the serving layer's variable batches
+        reuse cached programs)."""
         L_ = q_ids.shape[0]
-        if L_ == 0:
-            # an empty batch has a well-defined answer, not a degenerate
-            # program shape (bucket_L would still pad to tp, but the
-            # [0, k] result needs no kernel at all)
-            return self.empty_result(0)
         Lp = self.bucket_L(L_)
         if Lp != L_:
             pad_i = np.full((Lp - L_, q_ids.shape[1]), -1, q_ids.dtype)
@@ -289,21 +251,36 @@ class PatternSearchEngine:
         mv = np.pad(mv, ((0, pad - mv.shape[0]), (0, 0)))
         q_norms = np.sqrt((np.where(q_vals > 0, q_vals, 0) ** 2).sum(1))
         q_norms = np.maximum(q_norms, 1e-12).astype(np.float32)
+        q = (jnp.asarray(mi), jnp.asarray(mv), jnp.asarray(q_norms))
+        if self.backend == "pallas_fused":
+            return ((self.f_tiles,) + q,
+                    {"block_query": self.tiling.query_tile(Lp)})
+        return (self.d_ids, self.d_vals, self.d_norms, self.d_docids) + q, {}
+
+    def lower(self, q_ids: np.ndarray, q_vals: np.ndarray):
+        """The ``jax.stages.Lowered`` program a ``[L, Qn]`` query batch
+        runs against the resident corpus (``.compile().as_text()`` shows
+        whether a Pallas kernel is in it as a ``tpu_custom_call``)."""
+        args, kwargs = self._program_args(q_ids, q_vals)
+        return self._search_fn.lower(*args, **kwargs)
+
+    def _search_arrays(self, q_ids: np.ndarray,
+                       q_vals: np.ndarray) -> SearchResult:
+        """q_ids/q_vals: [L, Qn] (pad < 0) -> the [L, k] top-k."""
+        L_ = q_ids.shape[0]
+        if L_ == 0:
+            # an empty batch has a well-defined answer, not a degenerate
+            # program shape (bucket_L would still pad to tp, but the
+            # [0, k] result needs no kernel at all)
+            return self.empty_result(0)
+        args, kwargs = self._program_args(q_ids, q_vals)
         # optional device-stage split (DESIGN.md §8.5): with the fence
         # on, the async dispatch is timed separately from the device
         # compute it enqueues. Off by default — block_until_ready
         # serializes work the np.asarray below would have overlapped.
         fence = getattr(self.obs, "device_fence", False)
         t0 = time.perf_counter() if fence else 0.0
-        if self.backend == "pallas_fused":
-            tq = self.tiling.query_tile(Lp)
-            v, i = self._search_fn(self.f_tiles, jnp.asarray(mi),
-                                   jnp.asarray(mv), jnp.asarray(q_norms),
-                                   block_query=tq)
-        else:
-            v, i = self._search_fn(
-                self.d_ids, self.d_vals, self.d_norms, self.d_docids,
-                jnp.asarray(mi), jnp.asarray(mv), jnp.asarray(q_norms))
+        v, i = self._search_fn(*args, **kwargs)
         if fence:
             t1 = time.perf_counter()
             jax.block_until_ready((v, i))
@@ -378,11 +355,8 @@ class PatternSearchEngine:
         rows = self.ctx.dp_size
         slab = slab.pad_docs_to(-(-slab.n_docs // rows) * rows)
         if self.backend == "pallas_fused":
-            tiles, _, _ = kfused.tile_stream(
-                kfused.corpus_to_stream(slab),
-                block_docs=self._block_docs, nnz_pad=self.cfg.nnz_pad,
-                pad_docs_to=slab.n_docs)
-            return PackedSlab(jax.device_put(tiles))
+            return self._device_tiles(kfused.corpus_to_stream(slab),
+                                      slab.n_docs)[0]
         ids = slab.ids
         if self.backend == "pallas_packed":
             _require_integral_counts(slab.vals, self.backend)
@@ -405,10 +379,13 @@ class PatternSearchEngine:
         if self.backend != "pallas_fused":
             raise ValueError("put_stream_slab is the fused-backend "
                              f"ingest; engine backend is {self.backend!r}")
-        tiles, n_docs, n_trunc = kfused.tile_stream(
+        return self._device_tiles(stream, pad_docs_to)
+
+    def _device_tiles(self, stream: np.ndarray, pad_docs_to: Optional[int]
+                      ) -> Tuple[PackedSlab, int, int]:
+        return kfused.device_tiles(
             stream, block_docs=self._block_docs, nnz_pad=self.cfg.nnz_pad,
-            pad_docs_to=pad_docs_to)
-        return PackedSlab(jax.device_put(tiles)), n_docs, n_trunc
+            pad_docs_to=pad_docs_to, device=self._device)
 
     def _as_device(self, slab: Optional[SlabLike]) -> Optional[SlabLike]:
         if slab is None or isinstance(slab, (DeviceSlab, PackedSlab)):
@@ -423,6 +400,50 @@ class PatternSearchEngine:
         else:
             eng.d_ids, eng.d_vals, eng.d_norms, eng.d_docids = dev
         return eng
+
+
+def slab_program(cfg: SearchConfig, ctx: MeshCtx, backend: str,
+                 on_trace=None):
+    """The staged backends' jitted scoring program: a corpus slab sharded
+    over ``ctx.mesh`` (ids, vals, norms, doc ids) + a merged query batch
+    -> the global (vals [L, k], ids [L, k]). Each device scores its
+    corpus shard against its query columns (``kops.correlate``), takes a
+    local top-k, and a tree reduction over the corpus axes returns the
+    winners. ``on_trace(key)`` runs once per trace (per compiled
+    program) with the (L bucket, Q capacity, slab rows) key."""
+    tp = ctx.tp_axis
+    dp = ctx.dp_axes
+
+    def local_score(ids, vals, norms, docids, q_ids, q_vals, q_norms):
+        """Per-device: score local corpus shard x local query columns."""
+        corr = kops.correlate(
+            ids, vals, q_ids, q_vals, backend=backend,
+            vocab_size=cfg.vocab_size, block_docs=cfg.block_docs,
+            block_query=cfg.block_query)
+        cos = kops.cosine_scores(corr, norms, q_norms)
+        v, i = topk_lib.local_topk(cos, docids, cfg.top_k)
+        # reduce across the corpus-shard (K) axes — paper's report path
+        for ax in dp:
+            v, i = topk_lib.tree_topk(v, i, cfg.top_k, ax)
+        return v, i
+
+    qcols_spec = P(None, tp)  # L value-columns over the model axis
+
+    @jax.jit
+    def search(ids, vals, norms, docids, q_ids, q_vals, q_norms):
+        # python side effect: runs once per trace (i.e. per compiled
+        # program), never on a jit cache hit
+        if on_trace is not None:
+            on_trace((q_norms.shape[0], q_ids.shape[0], ids.shape[0]))
+        f = shard_map(
+            local_score, mesh=ctx.mesh,
+            in_specs=(P(dp, None), P(dp, None), P(dp), P(dp),
+                      P(None), qcols_spec, P(tp)),
+            out_specs=(P(tp, None), P(tp, None)),
+            check_vma=False)
+        return f(ids, vals, norms, docids, q_ids, q_vals, q_norms)
+
+    return search
 
 
 def eng_search(eng: PatternSearchEngine, q_ids, q_vals) -> SearchResult:

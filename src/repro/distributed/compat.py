@@ -1,50 +1,33 @@
-"""Version compatibility for jax APIs the codebase targets.
+"""The one ``shard_map`` import site of the repo.
 
-The code is written against the modern ``jax.shard_map`` surface
-(``check_vma``, ``axis_names``).  On older jax (< 0.6) only
-``jax.experimental.shard_map`` exists, with ``check_rep`` instead of
-``check_vma`` and ``auto`` (the complement set) instead of
-``axis_names``.  This wrapper presents the modern keyword surface on
-both and is the only ``shard_map`` import site the repo should use.
+A thin keyword wrapper over ``jax.shard_map`` (``check_vma``,
+``axis_names``), so call sites pass ``axis_names`` as any iterable and
+leave unset options at JAX's defaults.
 """
 from __future__ import annotations
 
 import functools
 from typing import Optional
 
-try:  # jax >= 0.6: top-level export with the modern kwargs
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-    _MODERN = True
-except ImportError:  # jax 0.4.x/0.5.x experimental API
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _MODERN = False
+from jax import shard_map as _shard_map
 
 
 def shard_map(f=None, *, mesh, in_specs, out_specs,
               check_vma: Optional[bool] = None,
               axis_names=None):
-    """``jax.shard_map`` with a stable keyword surface across versions.
+    """``jax.shard_map`` with optional keywords left at JAX's defaults.
 
     ``axis_names``: the mesh axes that are manual inside ``f`` (all axes
-    when None).  ``check_vma``: varying-manual-axes checking (named
-    ``check_rep`` before jax 0.6).
+    when None).  ``check_vma``: varying-manual-axes checking.
     """
     if f is None:
         return functools.partial(
             shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=check_vma, axis_names=axis_names)
-    if _MODERN:
-        kwargs = {}
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
     kwargs = {}
     if check_vma is not None:
-        kwargs["check_rep"] = check_vma
+        kwargs["check_vma"] = check_vma
     if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
+        kwargs["axis_names"] = set(axis_names)
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                       **kwargs)

@@ -36,9 +36,12 @@ class MeshCtx:
         return NamedSharding(self.mesh, P(*spec))
 
 
-def single_device_ctx() -> MeshCtx:
+def single_device_ctx(device=None) -> MeshCtx:
     """1-device mesh with production axis names — smoke tests run the exact
-    same (shard_map-containing) code paths on CPU."""
-    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    same (shard_map-containing) code paths on CPU. ``device`` picks the
+    chip (None: the first of ``jax.devices()``), so several single-chip
+    sessions of one process can each own a different chip."""
+    device = jax.devices()[0] if device is None else device
+    mesh = Mesh(np.array([device]).reshape(1, 1), ("data", "model"))
     return MeshCtx(mesh=mesh, dp_axes=("data",), fsdp_axis="data",
                    tp_axis="model")

@@ -9,36 +9,41 @@ no intermediate materialization. The staged software path still runs
 
 as three dispatches with a host-resident ELL intermediate ([D, K] int32
 ids + [D, K] float32 vals + norms) between the first two. This module
-collapses the chain into one ``pallas_call`` over the packed uint32
-stream itself:
+collapses the chain into one ``pallas_call`` over the packed stream
+itself:
 
   - **decode** — in-kernel VPU shifts/masks split each 32-bit word into
     header (bit 31 set: ``[1 | docID:31]``) or pair (``[0 | wordID:19 |
-    count:12]``); a cumulative sum over the header bits assigns every
-    word to its document row, and a one-hot row matrix turns segment
-    reductions (per-doc norm, per-doc score) into MXU matmuls;
-  - **match** — the same merge-join -> match-matrix reformulation as
-    ``sparse_match``: ``eq = (ids == q_ids)``, ``eq @ q_vals``, scaled
-    by the decoded counts and segment-summed per document row;
+    count:12]``). A prefix count of the header bits (two exact 0/1
+    matmuls on the MXU) assigns every word to its document row; a
+    one-hot row matrix then turns segment reductions (doc id, per-doc
+    norm, per-doc score) into MXU matmuls;
+  - **match** — ``sparse_match.match_row`` one 128-word row at a time:
+    ``eq = (q_ids == ids)``, ``q_vals^T @ eq``, scaled by the decoded
+    counts and folded per document row;
   - **top-k** — the epilogue (last query-tile grid step) computes the
     cosine scores against in-kernel doc norms and emits each doc tile's
-    ``min(k, block_docs)`` best candidates; the host-side wrapper folds
-    the per-tile candidate lists with the ``core.topk`` primitives.
+    ``min(k, block_docs)`` best candidates by k rounds of
+    max-and-mask; the host-side wrapper folds the per-tile candidate
+    lists with the ``core.topk`` primitives.
 
 Host staging is reduced to ``tile_stream``: an O(n) boundary-index pass
 that splits the raw stream at document boundaries into fixed-capacity
-``[T, cap]`` uint32 tiles (``cap = block_docs * (1 + nnz_pad)``, pad
-word 0xFFFFFFFF) so no document straddles a grid block. No ELL arrays,
-no float conversion, no norms are materialized on the host — 4 B/word
-travels to the device exactly as it sits in the segment file.
+tiles (``cap = block_docs * (1 + nnz_pad)`` words, pad word 0xFFFFFFFF)
+so no document straddles a grid block. On upload (``device_tiles``) each
+tile is laid out as ``[R, 128]`` words — the capacity rounded up to whole
+(8, 128) vreg tiles — and reinterpreted as int32, the layout and dtype
+Mosaic's block and cast rules accept. No ELL arrays, no float
+conversion, no norms are materialized on the host — 4 B/word travels to
+the device exactly as it sits in the segment file.
 
 Numerics: counts are 12-bit integers, so in the no-overflow regime
 (score and norm partial sums below 2**24) every accumulation order is
-exact in fp32 and the fused result is *bit-identical* to the staged
-``jnp`` reference — including IEEE-correctly-rounded ``sqrt`` for the
-norms (fp64->fp32 double rounding of sqrt is innocuous at these
-widths). tests/test_fused_kernel.py proves this on every serving
-surface.
+exact in fp32, every MXU operand is split exactly into bf16 parts
+(``sparse_match.split3``), and the fused result is *bit-identical* to
+the staged ``jnp`` reference in interpret mode — including the ``sqrt``
+of the norms. tests/test_fused_kernel.py proves this on every serving
+surface. On the chip, division and ``sqrt`` follow the TPU's rounding.
 
 Tiling (``block_docs``, ``block_query``) comes from the strategy
 classes in ``kernels.tiling``; shapes are memoized per L-bucket so the
@@ -55,32 +60,40 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:                                      # scratch constructors (TPU path
-    from jax.experimental.pallas import tpu as pltpu   # + interpret mode)
-except ImportError:                       # pragma: no cover - old jax
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.stream_format import (HEADER_BIT, KEY_BITS, KEY_MASK,
                                       MAX_DOC_ID, VAL_BITS, VAL_MASK)
+from repro.kernels.sparse_match import DOC_PAD, match_row, split3
 
 Array = jax.Array
 
 PAD_WORD = np.uint32(0xFFFFFFFF)
+LANES = 128
+_HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))            # contract the lane dims: a @ b.T
 
 
 class PackedSlab(NamedTuple):
     """A corpus slab in fused-kernel layout: the Fig. 8 stream split
-    into fixed-capacity doc tiles, still packed uint32. The fused
-    scoring unit — the counterpart of the staged path's DeviceSlab."""
-    tiles: jax.Array      # [T, cap] uint32 (PAD_WORD padding)
+    into fixed-capacity doc tiles, still packed. The fused scoring unit
+    — the counterpart of the staged path's DeviceSlab."""
+    tiles: jax.Array      # [T, R, 128] int32 view of the uint32 words
 
 
 # ---------------------------------------------------------------------------
 # host-side stream tiling (boundary index pass — NOT an ELL decode)
 # ---------------------------------------------------------------------------
+def kernel_width(block_docs: int, nnz_pad: int) -> int:
+    """Words per tile in the kernel layout: the tile capacity rounded up
+    to whole (8, 128) 32-bit vreg tiles."""
+    cap = block_docs * (1 + nnz_pad)
+    return -(-cap // (8 * LANES)) * 8 * LANES
+
+
 def tile_stream(stream: np.ndarray, *, block_docs: int, nnz_pad: int,
-                pad_docs_to: Optional[int] = None
+                pad_docs_to: Optional[int] = None,
+                width: Optional[int] = None
                 ) -> Tuple[np.ndarray, int, int]:
     """Split a Fig. 8 uint32 stream into ``[T, cap]`` fixed-capacity doc
     tiles for the fused kernel. Applies the exact truncation rule of
@@ -90,11 +103,16 @@ def tile_stream(stream: np.ndarray, *, block_docs: int, nnz_pad: int,
     ``pad_docs_to`` pads the tile count to ``ceil(pad_docs_to /
     block_docs)`` (all-PAD rows) so every segment of a store shares one
     program shape — the fused analogue of ``Corpus.pad_docs_to``.
+    ``width`` (>= cap) widens every tile with PAD words, e.g. to
+    ``kernel_width``.
 
     Returns ``(tiles, n_docs, n_truncated)``.
     """
     stream = np.asarray(stream, np.uint32)
     cap = block_docs * (1 + nnz_pad)
+    width = cap if width is None else int(width)
+    if width < cap:
+        raise ValueError(f"width {width} < tile capacity {cap}")
     is_hdr = (stream & HEADER_BIT) != 0
     n_docs = int(is_hdr.sum())
     target = n_docs if pad_docs_to is None else int(pad_docs_to)
@@ -102,7 +120,7 @@ def tile_stream(stream: np.ndarray, *, block_docs: int, nnz_pad: int,
         raise ValueError(f"pad_docs_to {target} < n_docs {n_docs}")
     n_tiles = -(-target // block_docs) if target else 0
     if n_docs == 0:
-        return np.full((n_tiles, cap), PAD_WORD, np.uint32), 0, 0
+        return np.full((n_tiles, width), PAD_WORD, np.uint32), 0, 0
     if bool((stream == PAD_WORD).any()):
         # header word of doc_id MAX_DOC_ID collides with the pad
         # sentinel; the staged backends handle it, the fused one refuses
@@ -123,9 +141,23 @@ def tile_stream(stream: np.ndarray, *, block_docs: int, nnz_pad: int,
     tile_of = doc_of // block_docs
     tile_base = hdr_pos_k[tile_of * block_docs]    # tile's first word
     col = np.arange(kept.size) - tile_base
-    tiles = np.full((n_tiles, cap), PAD_WORD, np.uint32)
+    tiles = np.full((n_tiles, width), PAD_WORD, np.uint32)
     tiles[tile_of, col] = kept
     return tiles, n_docs, n_trunc
+
+
+def device_tiles(stream: np.ndarray, *, block_docs: int, nnz_pad: int,
+                 pad_docs_to: Optional[int] = None,
+                 device=None) -> Tuple[PackedSlab, int, int]:
+    """``tile_stream`` at ``kernel_width``, reshaped to ``[T, R, 128]``,
+    viewed as int32 and uploaded to ``device`` (None: JAX's default).
+    Returns ``(slab, n_docs, n_truncated)``."""
+    width = kernel_width(block_docs, nnz_pad)
+    tiles, n_docs, n_trunc = tile_stream(
+        stream, block_docs=block_docs, nnz_pad=nnz_pad,
+        pad_docs_to=pad_docs_to, width=width)
+    tiles = tiles.view(np.int32).reshape(-1, width // LANES, LANES)
+    return PackedSlab(jax.device_put(tiles, device)), n_docs, n_trunc
 
 
 def corpus_to_stream(corpus) -> np.ndarray:
@@ -167,118 +199,168 @@ def corpus_to_stream(corpus) -> np.ndarray:
 # ---------------------------------------------------------------------------
 # the fused kernel
 # ---------------------------------------------------------------------------
-def _fused_kernel(tiles_ref, q_ids_ref, q_vals_ref, q_norms_ref,
+def _fused_kernel(tiles_ref, q_ref, qv_ref, qn_ref,
                   vals_out_ref, ids_out_ref,
-                  corr_ref, dnorm_ref, docid_ref, *, kp: int, nq: int):
-    """Grid (doc_tiles, query_tiles), query axis innermost. Scratch
-    (corr accumulator, doc norms, doc ids) persists across the query
-    axis; the epilogue runs once per doc tile at the last query step."""
+                  ids_scr, vals_scr, row_scr, stats_scr, corr_scr, *,
+                  nq: int):
+    """Grid (doc_tiles, query_tiles), query axis innermost. The decoded
+    tile (word ids, counts, doc row per word), the per-doc stats and the
+    correlation accumulator persist in scratch across the query axis;
+    the prologue decodes once per doc tile, the epilogue ranks once."""
     j = pl.program_id(1)
-    words = tiles_ref[0, :]                          # [cap] uint32
-    cap = words.shape[0]
-    bd = docid_ref.shape[0]
+    n_rows = tiles_ref.shape[1]
+    lk, bd = corr_scr.shape
+    kp = vals_out_ref.shape[2]
+    f32 = jnp.float32
+    slot = jax.lax.broadcasted_iota(jnp.int32, (bd, LANES), 0)
 
-    # -- in-kernel Fig. 8 decode (VPU shifts/masks) --------------------
-    is_pad = words == jnp.uint32(PAD_WORD)
-    is_hdr = jnp.logical_and((words & jnp.uint32(HEADER_BIT)) != 0,
-                             jnp.logical_not(is_pad))
-    valid_pair = jnp.logical_and(jnp.logical_not(is_pad),
-                                 jnp.logical_not(is_hdr))
-    row = jnp.cumsum(is_hdr.astype(jnp.int32)) - 1   # doc row per word
-    onehot = row[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (cap, bd), 1)                     # word -> doc row
-    d_ids = jnp.where(valid_pair,
-                      ((words >> VAL_BITS) & jnp.uint32(KEY_MASK))
-                      .astype(jnp.int32), -1)
-    d_vals = jnp.where(valid_pair,
-                       (words & jnp.uint32(VAL_MASK)).astype(jnp.float32),
-                       0.0)
+    def onehot(row):                      # [1, 128] doc rows -> [bd, 128]
+        return (slot == row).astype(jnp.bfloat16)
 
     @pl.when(j == 0)
     def _prologue():
-        oh = onehot.astype(jnp.float32)
-        # per-doc L2 norm of the decoded counts (segment sum via MXU)
-        sumsq = jnp.dot(d_vals * d_vals, oh,
-                        preferred_element_type=jnp.float32)      # [bd]
-        dnorm_ref[...] = jnp.sqrt(sumsq)
-        hdr_id = jnp.where(is_hdr,
-                           (words & jnp.uint32(MAX_DOC_ID))
-                           .astype(jnp.int32), -1)
-        docid_ref[...] = jnp.max(
-            jnp.where(onehot, hdr_id[:, None], -1), axis=0)      # [bd]
-        corr_ref[...] = jnp.zeros_like(corr_ref)
+        # -- in-kernel Fig. 8 decode (VPU shifts/masks) ----------------
+        w = tiles_ref[0]                                 # [R, 128] int32
+        is_pad = w == -1                                 # PAD_WORD
+        is_hdr = jnp.logical_and(w < 0, jnp.logical_not(is_pad))
+        pair = jnp.logical_not(jnp.logical_or(is_pad, is_hdr))
+        ids_scr[...] = jnp.where(pair, (w >> VAL_BITS) & KEY_MASK, DOC_PAD)
+        vals_scr[...] = jnp.where(pair, (w & VAL_MASK).astype(f32), 0.0)
+        # doc row per word = headers at or before it in stream order - 1:
+        # an inclusive prefix within each 128-word row plus the headers
+        # of all earlier rows, both as exact 0/1 matmuls
+        h = is_hdr.astype(f32)
+        a = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+        b = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+        within = jnp.dot(h, (a <= b).astype(f32), precision=_HIGHEST,
+                         preferred_element_type=f32)
+        totals = jnp.dot(h, jnp.ones((LANES, LANES), f32),
+                         precision=_HIGHEST, preferred_element_type=f32)
+        r = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_rows), 0)
+        s = jax.lax.broadcasted_iota(jnp.int32, (n_rows, n_rows), 1)
+        before = jnp.dot((s < r).astype(f32), totals, precision=_HIGHEST,
+                         preferred_element_type=f32)
+        row_scr[...] = (within + before).astype(jnp.int32) - 1
 
-    # -- match: merge-join as a match matrix (MXU) ---------------------
-    eq = (d_ids[:, None] == q_ids_ref[...][None, :]).astype(jnp.float32)
-    matched = jnp.dot(eq, q_vals_ref[...].astype(jnp.float32),
-                      preferred_element_type=jnp.float32)   # [cap, L]
-    pp = d_vals[:, None] * matched
-    corr_ref[...] += jnp.dot(onehot.astype(jnp.float32).T, pp,
-                             preferred_element_type=jnp.float32)  # [bd, L]
+        # -- per-doc stats, one-hot folded on the MXU ------------------
+        # rows 0-3: the header's doc id in bytes (each exact in bf16),
+        # row 4: header present, rows 5-7: split3 of the squared count
+        k16 = jax.lax.broadcasted_iota(jnp.int32, (16, LANES), 0)
+
+        def stats_row(i, acc):
+            wi = tiles_ref[0, pl.ds(i, 1), :]
+            hdr = jnp.logical_and(wi < 0, wi != -1)
+            doc = jnp.where(hdr, wi & MAX_DOC_ID, 0)
+            v = vals_scr[pl.ds(i, 1), :]
+            parts = [((doc >> (8 * c)) & 0xFF).astype(f32) for c in range(4)]
+            parts.append(hdr.astype(f32))
+            parts += [p.astype(f32) for p in split3(v * v)]
+            rows = jnp.zeros((16, LANES), f32)
+            for c, p in enumerate(parts):
+                rows = jnp.where(k16 == c, p, rows)
+            return acc + jax.lax.dot_general(
+                rows.astype(jnp.bfloat16), onehot(row_scr[pl.ds(i, 1), :]),
+                _NT, preferred_element_type=f32)            # [16, bd]
+
+        stats_scr[...] = jax.lax.fori_loop(0, n_rows, stats_row,
+                                           jnp.zeros((16, bd), f32))
+        corr_scr[...] = jnp.zeros_like(corr_scr)
+
+    # -- match: one 128-word row at a time (MXU) -----------------------
+    q_col, qv3 = q_ref[...], qv_ref[...]
+
+    def match(i, acc):
+        pp = vals_scr[pl.ds(i, 1), :] * match_row(
+            q_col, qv3, ids_scr[pl.ds(i, 1), :], lk)          # [lk, 128]
+        pp3 = jnp.concatenate([p.astype(f32) for p in split3(pp)], axis=0)
+        c = jax.lax.dot_general(pp3.astype(jnp.bfloat16),
+                                onehot(row_scr[pl.ds(i, 1), :]), _NT,
+                                preferred_element_type=f32)   # [3lk, bd]
+        return acc + ((c[:lk] + c[lk:2 * lk]) + c[2 * lk:])
+
+    corr_scr[...] += jax.lax.fori_loop(0, n_rows, match,
+                                       jnp.zeros((lk, bd), f32))
 
     # -- epilogue: cosine + per-tile partial top-k ---------------------
     @pl.when(j == nq - 1)
     def _epilogue():
-        doc_id = docid_ref[...]
-        denom = dnorm_ref[...][:, None] * q_norms_ref[...][None, :]
+        st = stats_scr[...]
+        b = st[0:4].astype(jnp.int32)
+        doc_id = b[0:1] | (b[1:2] << 8) | (b[2:3] << 16) | (b[3:4] << 24)
+        doc_id = jnp.where(st[4:5] > 0, doc_id, -1)                 # [1, bd]
+        dnorm = jnp.sqrt((st[5:6] + st[6:7]) + st[7:8])             # [1, bd]
+        denom = dnorm * qn_ref[...]                                 # [lk, bd]
         cos = jnp.where(denom > 0,
-                        corr_ref[...] / jnp.maximum(denom, 1e-12),
+                        corr_scr[...] / jnp.maximum(denom, 1e-12),
                         -jnp.inf)
         # invalid rows (tile padding) can never surface; real documents
         # keep their id whatever their score (see core.topk.local_topk)
-        cos = jnp.where(doc_id[:, None] >= 0, cos, -jnp.inf)
+        cos = jnp.where(doc_id >= 0, cos, -jnp.inf)
         # rank with NaN pinned above every finite score (lax.top_k's own
-        # totalorder outside Pallas); the in-kernel sort orders NaN
-        # *last*, which would let -inf padding displace a real document
-        # whose score went non-finite — the rename bug's sibling
+        # totalorder outside Pallas), ties to the lower row like top_k
         rank = jnp.where(jnp.isnan(cos), jnp.inf, cos)
-        _, idx = jax.lax.top_k(rank.T, kp)           # [L, kp]
-        v = jnp.take_along_axis(cos.T, idx, axis=1)
-        ids = jnp.take(doc_id, idx)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (lk, bd), 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, (lk, kp), 1)
+        ids_b = jnp.broadcast_to(doc_id, (lk, bd))
+
+        def pick(t, carry):
+            taken, out_v, out_i = carry
+            avail = jnp.where(taken > 0, -jnp.inf, rank)
+            best = jnp.max(avail, axis=1, keepdims=True)
+            hit = jnp.logical_and(avail == best, taken == 0)
+            idx = jnp.min(jnp.where(hit, lane, bd), axis=1, keepdims=True)
+            sel = lane == idx
+            v = jnp.max(jnp.where(sel, cos, -jnp.inf), axis=1, keepdims=True)
+            i = jnp.max(jnp.where(sel, ids_b, -1), axis=1, keepdims=True)
+            return (jnp.where(sel, 1, taken), jnp.where(col == t, v, out_v),
+                    jnp.where(col == t, i, out_i))
+
+        _, v, i = jax.lax.fori_loop(
+            0, kp, pick, (jnp.zeros((lk, bd), jnp.int32),
+                          jnp.full((lk, kp), -jnp.inf, f32),
+                          jnp.full((lk, kp), -1, jnp.int32)))
         vals_out_ref[...] = v[None]
-        ids_out_ref[...] = jnp.where(ids >= 0, ids, -1)[None]
+        ids_out_ref[...] = i[None]
 
 
 @functools.partial(jax.jit, static_argnames=("block_docs", "kp",
                                              "block_query", "interpret"))
-def fused_match_topk(tiles: Array, q_ids: Array, q_vals: Array,
+def fused_match_topk(tiles: Array, q_col: Array, qv3: Array,
                      q_norms: Array, *, block_docs: int, kp: int,
                      block_query: int = 512,
                      interpret: bool = False) -> Tuple[Array, Array]:
-    """tiles: [T, cap] uint32 (from ``tile_stream``, cap = block_docs *
-    (1 + nnz_pad)); q_ids: [Qm] int32 merged stream (pads already
-    remapped by ops.py so they can never match a decoded word id);
-    q_vals: [Qm, L]; q_norms: [L]. Qm % block_query == 0 (ops.py pads).
-    Returns per-tile candidates (vals [T, L, kp], ids [T, L, kp]) — fold
-    with ``core.topk.fold_topk``."""
-    T, cap = tiles.shape
-    Qm, L_ = q_vals.shape
-    tq = min(block_query, Qm)
-    assert Qm % tq == 0, (Qm, tq)
-    nq = Qm // tq
-    grid = (T, nq)
-    scratch = []
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((block_docs, L_), jnp.float32),
-                   pltpu.VMEM((block_docs,), jnp.float32),
-                   pltpu.VMEM((block_docs,), jnp.int32)]
+    """tiles: [T, R, 128] int32 (from ``device_tiles``); ``q_col`` [Qp,
+    1] / ``qv3`` [3·Lk, Qp] from ``sparse_match.query_operands``;
+    q_norms: [Lk, 1]. Qp % block_query == 0 (ops.py pads). Returns
+    per-tile candidates (vals [T, Lk, kp], ids [T, Lk, kp]) — fold with
+    ``core.topk.fold_topk``."""
+    T, n_rows, _ = tiles.shape
+    Qp = q_col.shape[0]
+    lk = qv3.shape[0] // 3
+    tq = min(block_query, Qp)
+    assert Qp % tq == 0, (Qp, tq)
+    nq = Qp // tq
     return pl.pallas_call(
-        functools.partial(_fused_kernel, kp=kp, nq=nq),
-        grid=grid,
+        functools.partial(_fused_kernel, nq=nq),
+        grid=(T, nq),
         in_specs=[
-            pl.BlockSpec((1, cap), lambda i, j: (i, 0)),
-            pl.BlockSpec((tq,), lambda i, j: (j,)),
-            pl.BlockSpec((tq, L_), lambda i, j: (j, 0)),
-            pl.BlockSpec((L_,), lambda i, j: (0,)),
+            pl.BlockSpec((1, n_rows, LANES), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((tq, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((3 * lk, tq), lambda i, j: (0, j)),
+            pl.BlockSpec((lk, 1), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, L_, kp), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, L_, kp), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, lk, kp), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, lk, kp), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((T, L_, kp), jnp.float32),
-            jax.ShapeDtypeStruct((T, L_, kp), jnp.int32),
+            jax.ShapeDtypeStruct((T, lk, kp), jnp.float32),
+            jax.ShapeDtypeStruct((T, lk, kp), jnp.int32),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((n_rows, LANES), jnp.int32),
+                        pltpu.VMEM((n_rows, LANES), jnp.float32),
+                        pltpu.VMEM((n_rows, LANES), jnp.int32),
+                        pltpu.VMEM((16, block_docs), jnp.float32),
+                        pltpu.VMEM((lk, block_docs), jnp.float32)],
         interpret=interpret,
-    )(tiles, q_ids, q_vals, q_norms)
+    )(tiles, q_col, qv3, q_norms)

@@ -2,7 +2,8 @@
 
 Handles padding to tile multiples, merged multi-query streams, sentinel
 conventions and cosine normalization. ``backend``:
-  - "pallas": the TPU kernel (interpret=True on CPU — used by tests)
+  - "pallas": the TPU kernel (interpret mode on CPU — used by tests;
+    see ``interpret_mode``)
   - "jnp":    gather-based scoring (engine default on CPU; also the
               in-memory CPU baseline of the paper's Fig. 13)
   - "pallas_packed": the Fig. 8 packed-word kernel (uint32 corpus)
@@ -22,7 +23,7 @@ import numpy as np
 from repro.core.topk import fold_topk
 from repro.kernels import ref as ref_mod
 from repro.kernels.fused import fused_match_topk
-from repro.kernels.sparse_match import sparse_match, QUERY_PAD
+from repro.kernels.sparse_match import query_operands, sparse_match
 from repro.kernels.sparse_match_packed import sparse_match_packed
 
 Array = jax.Array
@@ -62,6 +63,21 @@ def merge_queries(q_ids: np.ndarray, q_vals: np.ndarray
     return ids[order], vals[order]
 
 
+def interpret_mode() -> bool:
+    """Whether the Pallas backends run in interpret mode: on ``cpu``
+    (where the test suites run) yes, compiled by Mosaic on ``tpu``. Any
+    other platform raises — a Pallas backend never falls back to the
+    interpreter in silence."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas backends run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default backend is {platform!r} — use backend='jnp' there")
+
+
 @functools.partial(jax.jit, static_argnames=("backend", "block_docs",
                                              "block_query", "vocab_size"))
 def correlate(doc_ids: Array, doc_vals: Array, q_ids: Array, q_vals: Array,
@@ -75,34 +91,23 @@ def correlate(doc_ids: Array, doc_vals: Array, q_ids: Array, q_vals: Array,
         # well-defined zero correlation, not an empty-grid kernel launch
         return jnp.zeros((D, L_), jnp.float32)
     if backend in ("pallas", "pallas_packed"):
-        Qm = q_ids.shape[0]
         td = min(block_docs, max(D, 8))
-        tq = min(block_query, max(Qm, 8))
         Dp = -(-D // td) * td
-        # a zero-length merged stream (every query row empty) still pads
-        # to one full query tile: the kernel then scores all-pad items
-        # to the all-zero row instead of launching an empty grid whose
-        # output would be uninitialized
-        Qp = max(-(-Qm // tq) * tq, tq)
-        qi = _pad_to(q_ids, Qp, 0, QUERY_PAD)
-        qv = _pad_to(q_vals, Qp, 0, 0.0)
-        # query padding might collide with doc padding sentinel: remap
-        qi = jnp.where(qi < 0, QUERY_PAD, qi)
-        interpret = jax.default_backend() != "tpu"
+        q_col, qv3, tq, _ = query_operands(q_ids, q_vals, block_query)
+        interpret = interpret_mode()
         if backend == "pallas_packed":
-            # doc_ids here is the packed uint32 corpus (Fig. 8 in HBM);
-            # the pad sentinel must be a uint32 scalar — a bare python
-            # 0xFFFFFFFF overflows jnp.pad's int32 weak-type parsing
-            # whenever D is not a block multiple
-            dp = _pad_to(doc_ids, Dp, 0, np.uint32(0xFFFFFFFF))
-            out = sparse_match_packed(dp, qi, qv, block_docs=td,
+            # doc_ids here is the packed uint32 corpus (Fig. 8 in HBM),
+            # reinterpreted as int32 — the pad word is then -1
+            dp = _pad_to(jax.lax.bitcast_convert_type(doc_ids, jnp.int32),
+                         Dp, 0, -1)
+            out = sparse_match_packed(dp, q_col, qv3, block_docs=td,
                                       block_query=tq, interpret=interpret)
-            return out[:D]
-        di = _pad_to(doc_ids, Dp, 0, -1)
-        dv = _pad_to(doc_vals, Dp, 0, 0.0)
-        out = sparse_match(di, dv, qi, qv, block_docs=td, block_query=tq,
-                           interpret=interpret)
-        return out[:D]
+        else:
+            di = _pad_to(doc_ids, Dp, 0, -1)
+            dv = _pad_to(doc_vals, Dp, 0, 0.0)
+            out = sparse_match(di, dv, q_col, qv3, block_docs=td,
+                               block_query=tq, interpret=interpret)
+        return out[:L_, :D].T
     assert vocab_size > 0, "jnp backend needs vocab_size"
     qi = jnp.where(q_ids < 0, -1, q_ids)
     return ref_mod.sparse_match_ref(doc_ids, doc_vals, qi, q_vals, vocab_size)
@@ -119,10 +124,11 @@ def cosine_scores(corr: Array, doc_norms: Array, q_norms: Array) -> Array:
 def fused_topk(tiles: Array, q_ids: Array, q_vals: Array, q_norms: Array,
                *, k: int, block_docs: int, block_query: int = 512
                ) -> Tuple[Array, Array]:
-    """The ``pallas_fused`` scoring surface: packed doc tiles ([T, cap]
-    uint32 from ``kernels.fused.tile_stream``) + merged query stream ->
-    folded (vals [L, k], ids [L, k]) winners. One kernel replaces the
-    decode -> correlate -> local_topk dispatch chain (DESIGN.md §12).
+    """The ``pallas_fused`` scoring surface: packed doc tiles ([T, R,
+    128] int32 from ``kernels.fused.device_tiles``) + merged query
+    stream -> folded (vals [L, k], ids [L, k]) winners. One kernel
+    replaces the decode -> correlate -> local_topk dispatch chain
+    (DESIGN.md §12).
 
     Each doc tile emits its best ``min(k, block_docs)`` candidates —
     never explicit pad entries mid-stream — and the fold concatenates
@@ -136,17 +142,12 @@ def fused_topk(tiles: Array, q_ids: Array, q_vals: Array, q_norms: Array,
         # the staged path's local_topk padding produces
         return (jnp.full((L_, k), -jnp.inf, jnp.float32),
                 jnp.full((L_, k), -1, jnp.int32))
-    Qm = q_ids.shape[0]
-    tq = min(block_query, max(Qm, 8))
-    Qp = max(-(-Qm // tq) * tq, tq)      # >= one tile even when Qm == 0
-    qi = _pad_to(q_ids, Qp, 0, QUERY_PAD)
-    qi = jnp.where(qi < 0, QUERY_PAD, qi)
-    qv = _pad_to(q_vals, Qp, 0, 0.0)
-    interpret = jax.default_backend() != "tpu"
-    pv, pi = fused_match_topk(tiles, qi, qv, q_norms,
+    q_col, qv3, tq, lk = query_operands(q_ids, q_vals, block_query)
+    qn = _pad_to(q_norms.astype(jnp.float32), lk, 0, 0.0)[:, None]
+    pv, pi = fused_match_topk(tiles, q_col, qv3, qn,
                               block_docs=block_docs, kp=kp,
-                              block_query=tq, interpret=interpret)
+                              block_query=tq, interpret=interpret_mode())
     # concatenate per-tile candidates in tile order, then fold to k
-    cv = jnp.transpose(pv, (1, 0, 2)).reshape(L_, T * kp)
-    ci = jnp.transpose(pi, (1, 0, 2)).reshape(L_, T * kp)
+    cv = jnp.transpose(pv[:, :L_], (1, 0, 2)).reshape(L_, T * kp)
+    ci = jnp.transpose(pi[:, :L_], (1, 0, 2)).reshape(L_, T * kp)
     return fold_topk(cv, ci, k)

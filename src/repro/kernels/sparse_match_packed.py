@@ -7,8 +7,11 @@ sentinel 0xFFFFFFFF for padding — and unpacks in-kernel with VPU
 shifts/masks. 4 B/nnz halves HBM traffic per document; in the memory-bound
 single-query regime that is a straight 2x docs/s.
 
-The merge-join -> match-matrix reformulation is unchanged; only the
-operand encoding differs. ops.correlate(backend="pallas_packed") wraps it.
+The words reach the kernel reinterpreted as int32 (ops.py bitcasts), so
+every unpack is a signed-integer op Mosaic lowers; the pad sentinel is
+then -1. The merge-join -> match-matrix reformulation is shared with
+``sparse_match`` (``match_row``); only the operand encoding differs.
+ops.correlate(backend="pallas_packed") wraps it.
 """
 from __future__ import annotations
 
@@ -19,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 import numpy as np
+
+from repro.kernels.sparse_match import DOC_PAD, match_row
 
 Array = jax.Array
 
@@ -37,22 +42,22 @@ def pack(ids: Array, vals: Array) -> Array:
     return np.where(ids < 0, PAD_WORD, packed)
 
 
-def _kernel(docs_ref, q_ids_ref, q_vals_ref, out_ref):
+def _kernel(docs_ref, q_ref, qv_ref, out_ref):
     j = pl.program_id(1)
-    td, k = docs_ref.shape
-    tq, l = q_vals_ref.shape
+    td = docs_ref.shape[0]
+    lk = out_ref.shape[0]
+    q_col, qv3 = q_ref[...], qv_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (lk, td), 1)
 
-    packed = docs_ref[...].reshape(td * k)
-    d_ids = (packed >> VAL_BITS).astype(jnp.int32)       # 0x7FFFF+ for pads
-    d_vals = (packed & VAL_MASK).astype(jnp.float32)
-    valid = packed != jnp.uint32(0xFFFFFFFF)
-    d_ids = jnp.where(valid, d_ids, -1)
+    def row(d, acc):
+        w = docs_ref[pl.ds(d, 1), :]                 # [1, K] int32 words
+        valid = w != -1                              # PAD_WORD as int32
+        ids = jnp.where(valid, w >> VAL_BITS, DOC_PAD)
+        vals = (w & VAL_MASK).astype(jnp.float32)
+        pp = jnp.where(valid, vals * match_row(q_col, qv3, ids, lk), 0.0)
+        return jnp.where(lane == d, jnp.sum(pp, axis=1, keepdims=True), acc)
 
-    eq = (d_ids[:, None] == q_ids_ref[...].reshape(1, tq)).astype(jnp.float32)
-    matched = jnp.dot(eq, q_vals_ref[...].astype(jnp.float32),
-                      preferred_element_type=jnp.float32)  # [TD*K, L]
-    pp = jnp.where(valid[:, None], d_vals[:, None] * matched, 0.0)
-    scores = pp.reshape(td, k, l).sum(axis=1)
+    scores = jax.lax.fori_loop(0, td, row, jnp.zeros((lk, td), jnp.float32))
 
     @pl.when(j == 0)
     def _init():
@@ -65,27 +70,27 @@ def _kernel(docs_ref, q_ids_ref, q_vals_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_docs", "block_query",
                                              "interpret"))
-def sparse_match_packed(docs_packed: Array, q_ids: Array, q_vals: Array, *,
+def sparse_match_packed(docs_packed: Array, q_col: Array, qv3: Array, *,
                         block_docs: int = 128, block_query: int = 512,
                         interpret: bool = False) -> Array:
-    """docs_packed: [D, K] uint32 (Fig. 8 word packing); q_ids: [Qm]
-    (pad -2); q_vals: [Qm, L]. Returns correlation scores [D, L]."""
+    """docs_packed: [D, K] int32 view of the Fig. 8 words (pad -1);
+    ``q_col``/``qv3`` from ``sparse_match.query_operands``. Returns
+    transposed correlation scores [Lk, D]."""
     D, K = docs_packed.shape
-    Qm, L_ = q_vals.shape
+    Qp = q_col.shape[0]
+    lk = qv3.shape[0] // 3
     td = min(block_docs, D)
-    tq = min(block_query, Qm)
-    assert D % td == 0 and Qm % tq == 0, (D, td, Qm, tq)
-    grid = (D // td, Qm // tq)
-
+    tq = min(block_query, Qp)
+    assert D % td == 0 and Qp % tq == 0, (D, td, Qp, tq)
     return pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(D // td, Qp // tq),
         in_specs=[
             pl.BlockSpec((td, K), lambda i, j: (i, 0)),
-            pl.BlockSpec((tq,), lambda i, j: (j,)),
-            pl.BlockSpec((tq, L_), lambda i, j: (j, 0)),
+            pl.BlockSpec((tq, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((3 * lk, tq), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((td, L_), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((D, L_), jnp.float32),
+        out_specs=pl.BlockSpec((lk, td), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((lk, D), jnp.float32),
         interpret=interpret,
-    )(docs_packed, q_ids, q_vals)
+    )(docs_packed, q_col, qv3)

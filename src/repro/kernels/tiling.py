@@ -33,7 +33,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict
 
-DEFAULT_VMEM_BUDGET = 4 * 1024 * 1024   # bytes; ~25% of a TPU core's VMEM
+# the VMEM a v5e kernel's pipelined blocks may fill before Mosaic refuses
+# it: a compile for a described v5e accepts double-buffered 7 MiB blocks
+# and refuses 9 MiB ones ("ran out of memory in memory space vmem");
+# tests/test_tpu_compile.py holds the kernels at paper widths to it
+DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024
 
 
 def _pow2_floor(n: int) -> int:

@@ -6,8 +6,10 @@
 import argparse
 import time
 
+import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.paper_search import SearchConfig
 from repro.core import corpus as corpus_lib
 from repro.core.engine import PatternSearchEngine
@@ -23,10 +25,13 @@ def main():
     ap.add_argument("--nnz-pad", type=int, default=64)
     ap.add_argument("--queries", type=int, default=4)
     ap.add_argument("--top-k", type=int, default=10)
-    ap.add_argument("--backend", choices=["jnp", "pallas", "pallas_packed"],
+    ap.add_argument("--backend", choices=["jnp", "pallas", "pallas_packed",
+                                          "pallas_fused"],
                     default="jnp")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
+    dev = jax.devices()[0]
 
     cfg = SearchConfig(name="service", vocab_size=args.vocab,
                        avg_nnz_per_doc=args.avg_nnz, nnz_pad=args.nnz_pad,
@@ -51,7 +56,7 @@ def main():
     dt = time.time() - t0
     print(f"[search] {args.queries} queries x {args.n_docs} docs in "
           f"{dt*1e3:.1f} ms ({args.n_docs*args.queries/dt:.3e} "
-          f"doc-query pairs/s on CPU)")
+          f"doc-query pairs/s on {dev.platform} {dev.device_kind})")
     for l, i in enumerate(idxs):
         hit = "OK" if res.doc_ids[l, 0] == i else "MISS"
         print(f"  q{l} (doc {i}): top1 = doc {res.doc_ids[l, 0]} "

@@ -48,6 +48,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.paper_search import SearchConfig
 from repro.core import corpus as corpus_lib
 from repro.core.engine import PatternSearchEngine
@@ -104,7 +105,8 @@ def main():
     ap.add_argument("--nnz-pad", type=int, default=64)
     ap.add_argument("--query-nnz", type=int, default=48)
     ap.add_argument("--top-k", type=int, default=10)
-    ap.add_argument("--backend", choices=["jnp", "pallas", "pallas_packed"],
+    ap.add_argument("--backend", choices=["jnp", "pallas", "pallas_packed",
+                                          "pallas_fused"],
                     default="jnp")
     ap.add_argument("--clients", type=int, default=16)
     ap.add_argument("--requests", type=int, default=32,
@@ -203,6 +205,7 @@ def main():
                          "device time — measurement mode, adds sync")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.ingest and not (args.store or args.cluster):
         ap.error("--ingest needs --store or --cluster (the resident "
                  "engine has no write path)")

@@ -12,7 +12,7 @@ import pytest
 from repro.cluster import FlashClusterSession, build_sharded_store
 from repro.configs.paper_search import smoke
 from repro.core import corpus as corpus_lib
-from repro.obs import MetricsRegistry, Obs
+from repro.obs import NULL_SPAN, MetricsRegistry, Obs
 from repro.serve import (HedgePolicy, Query, QueryOptions, SpawnExecutor,
                          run_hedged)
 from repro.storage import FlashSearchSession, FlashStore
@@ -174,6 +174,14 @@ class _Slow:
         return getattr(self._inner, name)
 
 
+def _warm_replicas(sess, q):
+    """Open every replica's session and compile its program, so a hedge
+    races the straggler, not a cold replica's first compile."""
+    for s in range(sess.store.n_shards):
+        for r in range(sess.store.replicas):
+            sess.router._attempt(s, r, q, NULL_SPAN)
+
+
 def _cluster(tmp_path, cfg, n_shards=2, replicas=2, **kw):
     corpus = corpus_lib.synthesize(120, cfg.vocab_size, cfg.avg_nnz_per_doc,
                                    cfg.nnz_pad, seed=11)
@@ -190,14 +198,18 @@ def _cluster(tmp_path, cfg, n_shards=2, replicas=2, **kw):
 
 def test_hedge_outruns_slow_replica_bit_identically(tmp_path):
     cfg = smoke()
+    # a registry of its own: the hedge threshold is a percentile of the
+    # rolling shard-latency window, which must hold this cluster's warm
+    # shard times, not cold compiles of other tests in the process
     corpus, sess, union = _cluster(
-        tmp_path, cfg,
+        tmp_path, cfg, obs=Obs(registry=MetricsRegistry()),
         hedge_policy=HedgePolicy(percentile=0.5, min_ms=1.0, fallback_ms=20.0))
     try:
         qi, qv = corpus_lib.make_query(corpus, 7, cfg.max_query_nnz)
         q = Query(qi[None], qv[None])
         ref = union.search_typed(Query(qi[None], qv[None]))
-        sess.search_typed(q)                # open every primary replica
+        _warm_replicas(sess, q)
+        sess.search_typed(q)                # seed the window, warm
         # make shard 0's primary a straggler, far past the 20ms threshold
         sess.router._sessions[0][0] = _Slow(sess.router._sessions[0][0], 0.6)
         t0 = time.monotonic()
@@ -247,6 +259,7 @@ def test_hedge_per_query_opt_in_without_router_policy(tmp_path):
         qi, qv = corpus_lib.make_query(corpus, 5, cfg.max_query_nnz)
         q = Query(qi[None], qv[None])
         sess.search_typed(q)
+        _warm_replicas(sess, q)
         sess.router._sessions[1][0] = _Slow(sess.router._sessions[1][0], 0.5)
         # default fallback is 50ms; the 0.5s straggler trips it
         res = sess.search_typed(q, options=QueryOptions(hedging=True))

@@ -133,3 +133,26 @@ def test_property_query_batching_linear(seed):
         np.testing.assert_allclose(np.asarray(batched[:, l]),
                                    np.asarray(single[:, 0]), rtol=1e-5,
                                    atol=1e-5)
+
+
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None), ("metal", None)])
+def test_interpret_mode_by_platform(monkeypatch, platform, interpret):
+    """Pallas backends interpret on cpu (where the suites run), compile
+    on tpu, and refuse every other platform instead of falling back to
+    the interpreter in silence."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=platform):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is interpret
+
+
+def test_pallas_backend_raises_on_unknown_platform(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    ids, vals, mi, mv = _mk(40, 3, 5, 1, 64, seed=1)   # shapes unique here
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.correlate(jnp.asarray(ids), jnp.asarray(vals), jnp.asarray(mi),
+                      jnp.asarray(mv), backend="pallas", block_docs=8,
+                      block_query=8)

@@ -11,6 +11,7 @@ import pytest
 from repro.cluster import FlashClusterSession, build_sharded_store
 from repro.configs.paper_search import smoke
 from repro.core import corpus as corpus_lib
+from repro.obs import MetricsRegistry, Obs
 from repro.serve import HedgePolicy, Query, QueryOptions
 from repro.storage import FlashSearchSession, FlashStore
 from repro.storage.store import _corpus_docs
@@ -44,8 +45,10 @@ def cluster(tmp_path_factory):
     cl = build_sharded_store(str(tmp / "c2x2"), docs, n_shards=2,
                              replicas=2, policy="hash",
                              vocab_size=cfg.vocab_size, docs_per_segment=16)
+    # a registry of its own: the hedge timer reads the process's shard
+    # latency window otherwise, where other tests' cold compiles land
     sess = FlashClusterSession(
-        cl, cfg,
+        cl, cfg, obs=Obs(registry=MetricsRegistry()),
         hedge_policy=HedgePolicy(percentile=0.95, min_ms=1.0,
                                  fallback_ms=30.0))
     union = FlashStore.create(str(tmp / "u"), vocab_size=cfg.vocab_size,
