@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Iterable, NamedTuple, Optional, Tuple, Union
 
 import jax
@@ -46,6 +45,7 @@ from repro.kernels import ref as kref
 from repro.kernels.fused import PackedSlab
 from repro.kernels.sparse_match_packed import pack as pack_ell
 from repro.kernels.tiling import FixedTiling, TilingStrategy
+from repro.obs import NULL_SPAN, default_obs, stage
 
 
 @dataclasses.dataclass
@@ -89,11 +89,11 @@ class PatternSearchEngine:
                  tiling: Optional[TilingStrategy] = None):
         """``corpus=None`` builds a streaming-only engine (no resident
         corpus): callers must use ``search_streaming`` / ``put_slab``.
-        ``obs`` (a ``repro.obs.Obs``) mirrors compile traces into the
-        shared metrics registry; None uses the process default.
+        ``obs`` (a ``repro.obs.Obs``) takes the compile-trace counter
+        and each pass's ``slab_*`` stages; None uses the process
+        default.
         ``tiling`` picks the fused backend's tile shapes (DESIGN.md
         §12.3); None uses ``FixedTiling`` at the config's shapes."""
-        from repro.obs import default_obs
         self.cfg = cfg
         self.ctx = ctx
         self.backend = backend
@@ -273,28 +273,21 @@ class PatternSearchEngine:
             # program shape (bucket_L would still pad to tp, but the
             # [0, k] result needs no kernel at all)
             return self.empty_result(0)
-        args, kwargs = self._program_args(q_ids, q_vals)
-        # optional device-stage split (DESIGN.md §8.5): with the fence
-        # on, the async dispatch is timed separately from the device
-        # compute it enqueues. Off by default — block_until_ready
-        # serializes work the np.asarray below would have overlapped.
-        fence = getattr(self.obs, "device_fence", False)
-        t0 = time.perf_counter() if fence else 0.0
-        v, i = self._search_fn(*args, **kwargs)
-        if fence:
-            t1 = time.perf_counter()
-            jax.block_until_ready((v, i))
-            t2 = time.perf_counter()
-            reg = self.obs.registry
-            reg.histogram("stage_ms", stage="score_dispatch").observe(
-                (t1 - t0) * 1e3)
-            reg.histogram("stage_ms", stage="score_device").observe(
-                (t2 - t1) * 1e3)
-        v = np.asarray(v)[:L_]
-        # ids come from local_topk / the fused epilogue already masked by
-        # row validity; re-masking by isfinite here renamed real docs
-        # with non-finite fp32 scores to -1 (see core.topk.local_topk)
-        i = np.asarray(i)[:L_]
+        # the host path of one pass, split into stages (DESIGN.md §8.2):
+        # build and upload the merged query, dispatch the program, then
+        # wait for the device and copy the top-k back
+        reg = self.obs.registry
+        with stage(reg, NULL_SPAN, "slab_prep"):
+            args, kwargs = self._program_args(q_ids, q_vals)
+        with stage(reg, NULL_SPAN, "slab_dispatch"):
+            v, i = self._search_fn(*args, **kwargs)
+        with stage(reg, NULL_SPAN, "slab_wait"):
+            v = np.asarray(v)[:L_]
+            # ids come from local_topk / the fused epilogue already
+            # masked by row validity; re-masking by isfinite here renamed
+            # real docs with non-finite fp32 scores to -1 (see
+            # core.topk.local_topk)
+            i = np.asarray(i)[:L_]
         return SearchResult(doc_ids=i.astype(np.int64), scores=v)
 
     # ------------------------------------------------------------------

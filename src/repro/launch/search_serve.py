@@ -39,8 +39,8 @@ serves ``/metrics`` (Prometheus text with rolling-window gauges),
 stock latency/availability objectives; tune with ``--slo-ms`` /
 ``--slo-target``) and ``/debug/traces`` on 127.0.0.1 while the load
 runs. ``--profile-dir DIR`` arms ``/debug/profile`` (jax.profiler
-capture); ``--device-fence`` splits ``stage_ms{score}`` into dispatch
-vs device time.
+capture), whose timeline holds the program's ``repro.*`` stage spans
+beside the device ops.
 """
 import argparse
 import threading
@@ -199,10 +199,6 @@ def main():
                     help="arm /debug/profile: GET it to capture a "
                          "jax.profiler trace into DIR (needs "
                          "--telemetry-port)")
-    ap.add_argument("--device-fence", action="store_true",
-                    help="fence the score dispatch (block_until_ready) "
-                         "so stage_ms splits score into dispatch vs "
-                         "device time — measurement mode, adds sync")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     enable_compile_cache()
@@ -221,8 +217,7 @@ def main():
         else int(args.cache_mb * 1e6)
     # one Obs bundle for the whole process: every target publishes into
     # the same registry, so the post-run summary is target-agnostic
-    obs = Obs(trace_sample=args.trace_sample, slow_ms=args.slow_ms,
-              device_fence=args.device_fence)
+    obs = Obs(trace_sample=args.trace_sample, slow_ms=args.slow_ms)
     if args.store:
         from repro.storage import FlashSearchSession, FlashStore
         store = FlashStore.open(args.store)

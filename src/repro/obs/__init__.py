@@ -17,9 +17,11 @@ benchmark that wants clean numbers passes its own ``Obs()`` (or
 PR 8 adds the live plane on top: every counter/histogram carries a
 rolling-window twin (``obs/window.py``, §8.4), SLO burn states evaluate
 against those windows (``obs/slo.py``), and ``obs/server.py`` serves
-the whole bundle over HTTP. ``device_fence=True`` opts the engine into
-``block_until_ready`` fencing so ``stage_ms`` splits score time into
-dispatch vs device (default off: fencing serializes the pipeline).
+the whole bundle over HTTP. Each hot-path stage is timed by
+``stage()`` (``obs/trace.py``), which also writes a ``repro.<name>``
+annotation onto the profiler's timeline, so a ``jax.profiler`` capture
+splits the score stage into host preparation, dispatch and the wait on
+the device without fencing anything.
 """
 from __future__ import annotations
 
@@ -31,12 +33,14 @@ from typing import Dict, List, Optional
 
 from .metrics import (DEFAULT_MS_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry, NULL_METRIC, NULL_REGISTRY)
-from .trace import NULL_SPAN, QueryTrace, Span, Tracer
+from .trace import (NULL_SPAN, QueryTrace, Span, Stage, Tracer,
+                    name_os_thread, stage)
 
 __all__ = [
     "DEFAULT_MS_BUCKETS", "Counter", "Gauge", "Histogram",
     "MetricsRegistry", "NULL_METRIC", "NULL_REGISTRY", "NULL_SPAN",
-    "Obs", "QueryTrace", "Span", "Tracer", "default_obs",
+    "Obs", "QueryTrace", "Span", "Stage", "Tracer",
+    "default_obs", "name_os_thread", "stage",
 ]
 
 # fields mirrored one-to-one from a per-query SearchStats (or the
@@ -52,15 +56,13 @@ class Obs:
     def __init__(self, *, registry: Optional[MetricsRegistry] = None,
                  trace_sample: int = 0, slow_ms: float = 250.0,
                  keep_traces: int = 32, keep_queries: int = 256,
-                 window_s: float = 60.0, window_slices: int = 6,
-                 device_fence: bool = False):
+                 window_s: float = 60.0, window_slices: int = 6):
         self.enabled = True
         self.registry = (MetricsRegistry(window_s=window_s,
                                          window_slices=window_slices)
                          if registry is None else registry)
         self.tracer = Tracer(sample_every=trace_sample, keep=keep_traces)
         self.slow_ms = float(slow_ms)
-        self.device_fence = bool(device_fence)
         self._queries: deque = deque(maxlen=keep_queries)
         self._q_lock = threading.Lock()
 
@@ -74,7 +76,6 @@ class Obs:
         obs.registry = NULL_REGISTRY
         obs.tracer = Tracer(sample_every=0, keep=1)
         obs.slow_ms = math.inf
-        obs.device_fence = False
         obs._queries = deque(maxlen=1)
         obs._q_lock = threading.Lock()
         return obs

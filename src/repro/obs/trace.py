@@ -24,14 +24,28 @@ Two properties keep this safe on the hot path:
 the default) and ring-buffers the finished traces (``recent``,
 ``last_trace``) so any session/service/router can hand back its most
 recent ``QueryTrace`` without plumbing.
+
+``stage(registry, span, name, **attrs)`` is the one way a hot-path
+stage is timed. It opens a ``jax.profiler.TraceAnnotation``
+``repro.<name>`` (a host event on the profiler's clock, so a capture
+sets it against the device ops), observes ``stage_ms{stage=<name>}``,
+and opens ``span.child(name)`` when the query was sampled. On the
+``Obs.disabled()`` floor (null registry, ``NULL_SPAN``) it returns the
+shared ``NULL_STAGE``: no clock read, no annotation.
 """
 from __future__ import annotations
 
+import ctypes
 import itertools
+import sys
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+from .metrics import NULL_REGISTRY
 
 
 class Span:
@@ -213,3 +227,88 @@ class Tracer:
         with self._lock:
             traces = list(self.recent)
         return [t.to_dict() for t in traces]
+
+
+class Stage:
+    """One timed stage: profiler annotation ``repro.<name>``, optional
+    ``stage_ms`` histogram, and a child of ``parent`` (``NULL_SPAN``
+    unless the query was sampled). ``span`` is the child while the
+    stage runs; ``seconds`` is its duration once it has ended. Build it
+    through ``stage()``, or directly for an annotation with no
+    histogram (``hist=None``)."""
+    __slots__ = ("name", "attrs", "span", "seconds", "_hist", "_parent",
+                 "_ann", "_t0")
+
+    def __init__(self, name: str, hist=None, parent=NULL_SPAN, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self._hist = hist
+        self._parent = parent
+        self.span = NULL_SPAN
+        self.seconds = 0.0
+
+    def __enter__(self) -> "Stage":
+        self._ann = TraceAnnotation("repro." + self.name, **self.attrs)
+        self._ann.__enter__()
+        self.span = self._parent.child(self.name, **self.attrs)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = time.perf_counter() - self._t0
+        if self._hist is not None:
+            self._hist.observe(self.seconds * 1e3)
+        self.span.end()
+        self._ann.__exit__(*exc)
+
+
+class _NullStage:
+    """The ``Obs.disabled()`` floor: enters and exits doing nothing."""
+    __slots__ = ()
+    span = NULL_SPAN
+    seconds = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        pass
+
+
+NULL_STAGE = _NullStage()
+
+
+def stage(registry, span, name: str, *, observe: bool = True, **attrs):
+    """``with stage(registry, span, "score", segment=s):`` times one
+    stage (DESIGN.md §8.2). ``observe=False`` keeps the annotation and
+    the span but records no ``stage_ms`` histogram."""
+    if registry is NULL_REGISTRY and span is NULL_SPAN:
+        return NULL_STAGE
+    hist = registry.histogram("stage_ms", stage=name) if observe else None
+    return Stage(name, hist, span, **attrs)
+
+
+def _prctl():
+    """libc's ``prctl`` (Linux), or None where there is none."""
+    if not sys.platform.startswith("linux"):
+        return None
+    try:
+        fn = ctypes.CDLL(None).prctl
+    except (OSError, AttributeError):
+        return None
+    fn.argtypes = [ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong,
+                   ctypes.c_ulong, ctypes.c_ulong]
+    fn.restype = ctypes.c_int
+    return fn
+
+
+_PRCTL = _prctl()
+_PR_SET_NAME = 15
+
+
+def name_os_thread() -> None:
+    """Give the calling thread's OS thread its ``threading`` name (Linux
+    keeps 15 bytes), so the profiler's line for it carries that name."""
+    if _PRCTL is not None:
+        _PRCTL(_PR_SET_NAME,
+               threading.current_thread().name.encode()[:15], 0, 0, 0)

@@ -54,7 +54,7 @@ import threading
 import time
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
-from repro.obs import NULL_REGISTRY, Obs
+from repro.obs import NULL_REGISTRY, Obs, name_os_thread
 from repro.serve.api import DeadlineExceeded
 
 _SHUTDOWN = object()
@@ -324,6 +324,7 @@ class MicroBatcher:
         return t_timeout, "timeout"
 
     def _loop(self) -> None:
+        name_os_thread()
         heap: List[_Entry] = []
         oldest_sub = 0.0
         while True:

@@ -41,6 +41,7 @@ from typing import List, Optional
 import numpy as np
 
 from repro.core.engine import SearchResult
+from repro.obs import NULL_REGISTRY, NULL_SPAN, stage
 from repro.serve.admission import AdmissionController
 from repro.serve.api import (Query, QueryOptions, QueryStats, SearchResponse,
                              coerce_request, truncate_k)
@@ -217,7 +218,15 @@ class SearchService:
 
     def _run_batch(self, reqs: List[_Request]) -> None:
         """Scheduler-thread body: stack -> score -> demux. Runs entirely
-        on the batcher thread, so the searcher sees serialized calls."""
+        on the batcher thread, so the searcher sees serialized calls.
+        The whole batch is one ``repro.batch`` profiler annotation
+        (DESIGN.md §8.2), numbered by the batcher's flush count."""
+        reg = self.obs.registry if self.obs is not None else NULL_REGISTRY
+        with stage(reg, NULL_SPAN, "batch", observe=False,
+                   batch=self._batcher.stats.n_batches, size=len(reqs)):
+            self._serve_batch(reqs)
+
+    def _serve_batch(self, reqs: List[_Request]) -> None:
         # claim every future first: a client that cancelled while queued
         # is dropped here, and claiming makes later cancel() a no-op so
         # the demux set_result below can never race an InvalidStateError

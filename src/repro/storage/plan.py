@@ -31,7 +31,6 @@ counters.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import List, Optional
 
 import numpy as np
@@ -39,7 +38,7 @@ import numpy as np
 from repro.core import stream_format
 from repro.core.corpus import Corpus
 from repro.core.engine import _merge_results, _next_pow2
-from repro.obs import NULL_REGISTRY, NULL_SPAN
+from repro.obs import NULL_REGISTRY, NULL_SPAN, stage
 from repro.storage import filter as filter_lib
 from repro.storage import postings as postings_lib
 from repro.storage.prefetch import Prefetcher
@@ -230,17 +229,15 @@ def execute_plan(engine, view, plan: QueryPlan, q_ids: np.ndarray,
     ``span``/``registry`` are the §8 observability hooks: per-segment
     child spans (slab source, decode/upload ms) hang off ``span`` when
     a trace sampled this query (``NULL_SPAN`` otherwise — allocation-
-    free), and stage latencies land in the registry's ``stage_ms``
-    histograms. Neither touches the numeric path: scan order, fold
-    order, and every array op are identical with observability on,
-    off, or disabled."""
+    free), and each stage (decode, upload, score, merge) runs under
+    ``obs.stage``: a ``repro.<stage>`` profiler annotation plus its
+    ``stage_ms`` histogram. Neither touches the numeric path: scan
+    order, fold order, and every array op are identical with
+    observability on, off, or disabled."""
     reg = NULL_REGISTRY if registry is None else registry
-    h_decode = reg.histogram("stage_ms", stage="decode")
-    h_upload = reg.histogram("stage_ms", stage="upload")
-    h_score = reg.histogram("stage_ms", stage="score")
     # the Obs.disabled() floor (§8.1): with a null registry AND no trace
-    # span, every perf_counter() read below is dead weight — skip them
-    # all, so the disabled path costs zero clock syscalls per slab
+    # span every stage is the shared no-op and the prefetcher skips its
+    # clock too, so the disabled path costs zero clock reads per slab
     timed = not (reg is NULL_REGISTRY and span is NULL_SPAN)
 
     def load(step: PlanStep):
@@ -257,69 +254,60 @@ def execute_plan(engine, view, plan: QueryPlan, q_ids: np.ndarray,
                 lspan.end(source=SOURCE_CACHE)
                 return step, hit.slab
             stats.cache_misses += 1
-        t0 = time.perf_counter() if timed else 0.0
-        seg = view.segment(step.name)
-        if plan.mode == MODE_APPROX and seg.postings is not None:
+        # the stream is a zero-copy mmap view: its page-ins land in the
+        # decode stage, with the decode itself
+        with stage(reg, lspan, "decode", segment=step.name) as dec:
+            seg = view.segment(step.name)
             # approximate tier (§15): posting traversal picks the top-C
             # candidate pool, then ONLY those rows are decoded (page-
             # level partial decode) and re-ranked exactly through the
             # session backend. The mini-slab is keyed by the query, so
             # it is never admitted to the slab cache; a pre-postings
-            # segment file (postings is None) falls through to the
-            # exhaustive branch below.
-            pool = seg.postings.candidates(q_ids, q_vals, plan.candidates)
-            doc_ids, ids, vals, norms, n_trunc = postings_lib.gather_rows(
-                seg, pool, plan.nnz_pad)
+            # segment file (postings is None) takes the exhaustive load.
+            approx = plan.mode == MODE_APPROX and seg.postings is not None
+            rows = None                 # host ELL rows, uploaded below
+            if approx:
+                pool = seg.postings.candidates(q_ids, q_vals,
+                                               plan.candidates)
+                rows = postings_lib.gather_rows(seg, pool, plan.nnz_pad)
+            elif plan.fmt.startswith("fused"):
+                # the fused kernel decodes the Fig. 8 words on-device:
+                # the stream is only *tiled* here (a boundary-index
+                # pass), never staged through host ELL arrays (§12.2),
+                # and the tiles upload as they are built, so this load's
+                # upload stage is empty. Tiling copies, so the segment
+                # can be released right after.
+                slab, n_docs, n_trunc = engine.put_stream_slab(
+                    seg.stream(), pad_docs_to=plan.slab_docs)
+            else:
+                rows = stream_format.decode_to_ell(seg.stream(),
+                                                   plan.nnz_pad)
             view.release(step.name)
-            t1 = time.perf_counter() if timed else 0.0
+        if rows is not None:
+            doc_ids, ids, vals, norms, n_trunc = rows
             n_docs = int(doc_ids.size)
-            stats.docs_scored += n_docs
-            stats.pairs_truncated += n_trunc
+        stats.docs_scored += n_docs
+        stats.pairs_truncated += n_trunc
+        if approx:
             stats.approx_segments += 1
             stats.candidates += n_docs
             if n_docs == 0:
                 lspan.end(source=SOURCE_DISK, approx=True, candidates=0)
                 return step, None
-            # pow2 pad capped at the plan shape: candidate pools of any
-            # size compile O(log slab_docs) distinct programs
-            corpus = Corpus(doc_ids, ids, vals, norms).pad_docs_to(
-                min(plan.slab_docs, _next_pow2(n_docs)))
-            slab = engine.put_slab(corpus)
-            t2 = time.perf_counter() if timed else 0.0
-            if timed:
-                h_decode.observe((t1 - t0) * 1e3)
-                h_upload.observe((t2 - t1) * 1e3)
-                lspan.end(source=SOURCE_DISK, approx=True,
-                          candidates=n_docs,
-                          decode_ms=round((t1 - t0) * 1e3, 3),
-                          upload_ms=round((t2 - t1) * 1e3, 3))
+        with stage(reg, lspan, "upload", segment=step.name) as up:
+            if rows is not None:
+                # an approx pool pads to a pow2 capped at the plan shape:
+                # pools of any size compile O(log slab_docs) programs
+                pad = (min(plan.slab_docs, _next_pow2(n_docs)) if approx
+                       else plan.slab_docs)
+                slab = engine.put_slab(
+                    Corpus(doc_ids, ids, vals, norms).pad_docs_to(pad))
+        times = dict(decode_ms=round(dec.seconds * 1e3, 3),
+                     upload_ms=round(up.seconds * 1e3, 3))
+        if approx:
+            lspan.end(source=SOURCE_DISK, approx=True, candidates=n_docs,
+                      **times)
             return step, slab
-        if plan.fmt.startswith("fused"):
-            # the fused kernel decodes the Fig. 8 words on-device: the
-            # segment stream is only *tiled* here (a boundary-index
-            # pass), never staged through host ELL arrays (§12.2). The
-            # mmap view stays open until the tiles are built — tiling
-            # copies, so the segment can be released right after.
-            slab, n_docs, n_trunc = engine.put_stream_slab(
-                seg.stream(), pad_docs_to=plan.slab_docs)
-            view.release(step.name)
-            t1 = t2 = time.perf_counter() if timed else 0.0
-            stats.docs_scored += n_docs
-            stats.pairs_truncated += n_trunc
-        else:
-            doc_ids, ids, vals, norms, n_trunc = stream_format.decode_to_ell(
-                seg.stream(), plan.nnz_pad)
-            view.release(step.name)
-            t1 = time.perf_counter() if timed else 0.0
-            n_docs = int(doc_ids.size)
-            stats.docs_scored += n_docs
-            stats.pairs_truncated += n_trunc
-            corpus = Corpus(doc_ids, ids, vals, norms)
-            slab = engine.put_slab(corpus.pad_docs_to(plan.slab_docs))
-            t2 = time.perf_counter() if timed else 0.0
-        if timed:
-            h_decode.observe((t1 - t0) * 1e3)
-            h_upload.observe((t2 - t1) * 1e3)
         # admission is gated on the LIVE store generation still matching
         # the generation the plan's segment list was captured at: once a
         # fold/compact has moved it, this segment may be a graveyard
@@ -332,10 +320,7 @@ def execute_plan(engine, view, plan: QueryPlan, q_ids: np.ndarray,
                 plan.key_for(step.name), slab,
                 n_docs=n_docs, n_trunc=n_trunc,
                 admit=lambda: view.live_generation == plan.generation)
-        if timed:
-            lspan.end(source=SOURCE_DISK,
-                      decode_ms=round((t1 - t0) * 1e3, 3),
-                      upload_ms=round((t2 - t1) * 1e3, 3))
+        lspan.end(source=SOURCE_DISK, **times)
         return step, slab
 
     if plan.is_empty:
@@ -357,34 +342,28 @@ def execute_plan(engine, view, plan: QueryPlan, q_ids: np.ndarray,
     try:
         if mem_slab is not None:
             # scored while the prefetcher's worker loads the first slabs
-            sspan = span.child("score", segment="memtable")
-            t0 = time.perf_counter() if timed else 0.0
-            folds[-1] = engine.search_streaming(q_ids, q_vals, [mem_slab])
-            if timed:
-                h_score.observe((time.perf_counter() - t0) * 1e3)
-            sspan.end(source="memtable", docs=stats.memtable_docs)
+            with stage(reg, span, "score", segment="memtable") as mst:
+                folds[-1] = engine.search_streaming(q_ids, q_vals,
+                                                    [mem_slab])
+            mst.span.set(source="memtable", docs=stats.memtable_docs)
         if pf is not None:
             for step, slab in pf:
                 if slab is None:        # empty approx candidate pool
                     continue
-                sspan = span.child("score", segment=step.name,
-                                   rank=step.rank)
-                t0 = time.perf_counter() if timed else 0.0
-                r = engine.search_streaming(q_ids, q_vals, [slab])
-                folds[step.rank] = r
-                # a segment the vocab filter let through whose every
-                # real score is exactly 0 had no query-term overlap:
-                # a filter false positive (exact for bitmaps, the
-                # Bloom FPR made flesh) — surfaced per query so the
-                # fleet can see when a filter has gone saturated
-                if plan.filtered:
-                    sc = np.asarray(r.scores)
-                    fin = sc[np.isfinite(sc)]
-                    if fin.size == 0 or not np.any(fin != 0):
-                        stats.filter_fp_segments += 1
-                if timed:
-                    h_score.observe((time.perf_counter() - t0) * 1e3)
-                sspan.end()
+                with stage(reg, span, "score", segment=step.name,
+                           rank=step.rank):
+                    r = engine.search_streaming(q_ids, q_vals, [slab])
+                    folds[step.rank] = r
+                    # a segment the vocab filter let through whose every
+                    # real score is exactly 0 had no query-term overlap:
+                    # a filter false positive (exact for bitmaps, the
+                    # Bloom FPR made flesh) — surfaced per query so the
+                    # fleet can see when a filter has gone saturated
+                    if plan.filtered:
+                        sc = np.asarray(r.scores)
+                        fin = sc[np.isfinite(sc)]
+                        if fin.size == 0 or not np.any(fin != 0):
+                            stats.filter_fp_segments += 1
     finally:
         if pf is not None:
             pf.close()
@@ -392,16 +371,12 @@ def execute_plan(engine, view, plan: QueryPlan, q_ids: np.ndarray,
         wait_ms = pf.consumer_wait_s * 1e3
         reg.histogram("stage_ms", stage="prefetch_wait").observe(wait_ms)
         span.set(prefetch_wait_ms=round(wait_ms, 3))
-    mspan = span.child("merge")
-    t0 = time.perf_counter() if timed else 0.0
-    best = None
-    for r in folds:
-        if r is None:
-            continue
-        best = r if best is None else _merge_results(best, r,
-                                                     engine.cfg.top_k)
-    if timed:
-        reg.histogram("stage_ms", stage="merge").observe(
-            (time.perf_counter() - t0) * 1e3)
-    mspan.end(folds=sum(r is not None for r in folds))
+    n_folds = sum(r is not None for r in folds)
+    with stage(reg, span, "merge", folds=n_folds):
+        best = None
+        for r in folds:
+            if r is None:
+                continue
+            best = r if best is None else _merge_results(best, r,
+                                                         engine.cfg.top_k)
     return best

@@ -18,8 +18,9 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Callable, Generic, Iterable, Iterator, TypeVar
+
+from repro.obs import Stage, name_os_thread
 
 T = TypeVar("T")
 U = TypeVar("U")
@@ -42,8 +43,8 @@ class Prefetcher(Generic[T, U]):
         self._finished = False
         self._closed = False
         # timed=False is the Obs.disabled() floor: the blocking path
-        # skips its perf_counter pair too, so a fully-disabled scan does
-        # zero clock reads in this module (consumer_wait_s stays 0.0)
+        # skips its stage too, so a fully-disabled scan does zero clock
+        # reads here (consumer_wait_s stays 0.0)
         self._timed = timed
         # seconds the consumer spent blocked waiting on the worker: the
         # overlap telemetry (DESIGN.md §8.2) — 0 means the prefetcher
@@ -65,6 +66,7 @@ class Prefetcher(Generic[T, U]):
         return False
 
     def _run(self, it: Iterator[T], load: Callable[[T], U]):
+        name_os_thread()
         try:
             for item in it:
                 if self._stop.is_set():
@@ -85,9 +87,10 @@ class Prefetcher(Generic[T, U]):
             v = self._q.get_nowait()   # no clock reads on full overlap
         except queue.Empty:
             if self._timed:
-                t0 = time.perf_counter()
-                v = self._q.get()
-                self.consumer_wait_s += time.perf_counter() - t0
+                # annotation only: the scan observes the summed wait
+                with Stage("prefetch_wait") as st:
+                    v = self._q.get()
+                self.consumer_wait_s += st.seconds
             else:
                 v = self._q.get()
         if v is _DONE:

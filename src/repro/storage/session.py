@@ -37,7 +37,7 @@ import numpy as np
 from repro.configs.paper_search import SearchConfig
 from repro.core.engine import PatternSearchEngine, SearchResult
 from repro.distributed.meshctx import MeshCtx, single_device_ctx
-from repro.obs import NULL_REGISTRY, NULL_SPAN, Obs, default_obs
+from repro.obs import NULL_SPAN, Obs, default_obs, stage
 from repro.serve.api import (Query, QueryOptions, QueryStats, SearchResponse,
                              coerce_request, truncate_k)
 from repro.serve.session_surface import ServingSessionMixin
@@ -299,18 +299,12 @@ class FlashSearchSession(ServingSessionMixin):
         ``snap`` carries the memtable when the view is a snapshot):
         plan, then run the shared executor (DESIGN.md §4.1)."""
         reg = self.obs.registry
-        timed = not (reg is NULL_REGISTRY and span is NULL_SPAN)
-        pspan = span.child("plan")
-        t0 = time.perf_counter() if timed else 0.0
-        plan = self._planner.plan(view, q_ids, snap, mode=mode,
-                                  candidates=candidates)
-        if timed:
-            reg.histogram("stage_ms", stage="plan").observe(
-                (time.perf_counter() - t0) * 1e3)
-        pspan.end(segments_total=plan.segments_total,
-                  skipped=len(plan.skipped), cached=plan.n_cached,
-                  disk=plan.n_disk,
-                  skipped_names=plan.skipped[:16])
+        with stage(reg, span, "plan") as st:
+            plan = self._planner.plan(view, q_ids, snap, mode=mode,
+                                      candidates=candidates)
+        st.span.set(segments_total=plan.segments_total,
+                    skipped=len(plan.skipped), cached=plan.n_cached,
+                    disk=plan.n_disk, skipped_names=plan.skipped[:16])
         self._slab_docs = plan.slab_docs
         stats = SearchStats(segments_total=plan.segments_total,
                             segments_skipped=len(plan.skipped),

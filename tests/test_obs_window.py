@@ -250,23 +250,38 @@ class _CountingTime:
         return getattr(self._real, name)
 
 
-def test_disabled_obs_does_zero_clock_reads(tmp_path, monkeypatch):
+def _count_clock_reads(monkeypatch, *mods) -> _CountingTime:
+    """Route every perf_counter read of ``mods`` and of the stage helper
+    (``repro.obs.trace``) through one counting proxy."""
     import time as real_time
 
-    from repro.storage import plan as plan_mod
-    from repro.storage import prefetch as prefetch_mod
-    from repro.storage import session as session_mod
+    from repro.obs import trace as trace_mod
 
+    proxy = _CountingTime(real_time)
+    for mod in (trace_mod,) + mods:
+        if hasattr(mod, "time"):
+            monkeypatch.setattr(mod, "time", proxy)
+    return proxy
+
+
+def _small_store(tmp_path):
     corpus = corpus_lib.synthesize(120, CFG.vocab_size,
                                    CFG.avg_nnz_per_doc, CFG.nnz_pad, seed=5)
     root = str(tmp_path / "store")
     store = FlashStore.create(root, vocab_size=CFG.vocab_size,
                               docs_per_segment=40)
     store.append_corpus(corpus)
+    return corpus, root
 
-    proxy = _CountingTime(real_time)
-    for mod in (plan_mod, prefetch_mod, session_mod):
-        monkeypatch.setattr(mod, "time", proxy)
+
+def test_disabled_obs_does_zero_clock_reads(tmp_path, monkeypatch):
+    from repro.storage import plan as plan_mod
+    from repro.storage import prefetch as prefetch_mod
+    from repro.storage import session as session_mod
+
+    corpus, root = _small_store(tmp_path)
+    proxy = _count_clock_reads(monkeypatch, plan_mod, prefetch_mod,
+                               session_mod)
 
     qi, qv = corpus_lib.make_query(corpus, 3, CFG.max_query_nnz)
     off = FlashSearchSession(FlashStore.open(root), CFG, obs=Obs.disabled())
@@ -284,3 +299,37 @@ def test_disabled_obs_does_zero_clock_reads(tmp_path, monkeypatch):
     np.testing.assert_array_equal(r_on.doc_ids, r_off.doc_ids)
     np.testing.assert_array_equal(r_on.scores, r_off.scores)
     on.close()
+
+
+def test_disabled_obs_service_does_zero_clock_reads(tmp_path, monkeypatch):
+    """The floor holds through the engine's per-pass stages and the
+    service's batch annotation too: a coalesced batch served under
+    Obs.disabled() reads no clock in any module on the path."""
+    from repro.core import engine as engine_mod
+    from repro.serve import search_service as service_mod
+    from repro.serve.api import Query
+    from repro.storage import plan as plan_mod
+    from repro.storage import prefetch as prefetch_mod
+    from repro.storage import session as session_mod
+
+    corpus, root = _small_store(tmp_path)
+    proxy = _count_clock_reads(monkeypatch, plan_mod, prefetch_mod,
+                               session_mod, engine_mod, service_mod)
+    queries = [Query(*corpus_lib.make_query(corpus, d, CFG.max_query_nnz))
+               for d in (3, 50, 97)]
+
+    def serve(obs):
+        sess = FlashSearchSession(FlashStore.open(root), CFG, obs=obs)
+        with service_mod.SearchService(sess, max_batch=4) as svc:
+            rows = [f.result() for f in [svc.submit(q) for q in queries]]
+        sess.close()
+        return rows
+
+    off = serve(Obs.disabled())
+    assert proxy.reads == 0, (
+        f"Obs.disabled() service path read the clock {proxy.reads} times")
+    on = serve(Obs())
+    assert proxy.reads > 0
+    for a, b in zip(on, off):
+        np.testing.assert_array_equal(a.doc_ids, b.doc_ids)
+        np.testing.assert_array_equal(a.scores, b.scores)
