@@ -9,10 +9,14 @@ L value-columns over the ``model`` axis — the paper's L. Each device is one
 hierarchical reduction returns the global winners. Only queries (in) and
 top-k (out) cross the interconnect; the corpus never moves.
 
-Streaming mode handles corpora larger than aggregate HBM: fixed-size
-resident slabs are scored while the next slab is transferred
-(double-buffered, epoch-tagged — the prefetch-predictor analogue at host
-scope), with top-k merged across slabs.
+Streaming mode handles corpora larger than aggregate HBM: a pass scores
+a query batch against a sequence of fixed-size device slabs in three
+steps. ``prepare`` builds and uploads the merged query once; ``dispatch``
+starts the program on each slab as it arrives, without reading its
+result, so the device runs slab after slab while the host dispatches
+the next (and the storage prefetcher, DESIGN.md §3, uploads the one
+after); ``collect`` then brings every slab's top-k back in one copy, and
+the top-k is merged across slabs on the host (DESIGN.md §4.1).
 
 Serving mode (DESIGN.md §7) feeds ``search`` micro-batches of varying L
 from the SearchService coalescer. To keep variable L cheap, query shapes
@@ -26,7 +30,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Iterable, NamedTuple, Optional, Tuple, Union
+import itertools
+from typing import Iterable, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +72,13 @@ class DeviceSlab(NamedTuple):
 SlabLike = Union[Corpus, DeviceSlab, PackedSlab]
 
 
+class PreparedQuery(NamedTuple):
+    """A query batch in the program's merged-stream form, padded to its
+    compile bucket and uploaded: what every slab of a pass shares."""
+    args: tuple         # (merged ids, merged vals, query norms) on device
+    kwargs: dict        # the program's static arguments
+
+
 def _require_integral_counts(vals: np.ndarray, backend: str):
     """The packed/fused backends carry values in the Fig. 8 12-bit count
     field — arbitrary floats would be silently clipped/rounded."""
@@ -84,6 +96,10 @@ def _next_pow2(n: int) -> int:
 
 
 class PatternSearchEngine:
+    # set only on a view ``dispatch`` returned: its program's (vals, ids),
+    # device arrays until ``collect`` brings them to the host
+    _out: Optional[tuple] = None
+
     def __init__(self, corpus: Optional[Corpus], cfg: SearchConfig,
                  ctx: MeshCtx, backend: str = "jnp", obs=None,
                  tiling: Optional[TilingStrategy] = None):
@@ -231,89 +247,127 @@ class PatternSearchEngine:
         no wrapping, no shim warning (see serve/search_service.py)."""
         return self._search_arrays(*query.rows())
 
-    def _program_args(self, q_ids: np.ndarray, q_vals: np.ndarray):
-        """q_ids/q_vals: [L, Qn] (pad < 0) -> (args, kwargs) of this
-        engine's jitted program. L is padded to its compile bucket (next
-        power-of-two multiple of the model-axis size — the paper's L
-        query batch, bucketed so the serving layer's variable batches
-        reuse cached programs)."""
-        L_ = q_ids.shape[0]
-        Lp = self.bucket_L(L_)
-        if Lp != L_:
-            pad_i = np.full((Lp - L_, q_ids.shape[1]), -1, q_ids.dtype)
-            pad_v = np.zeros((Lp - L_, q_vals.shape[1]), q_vals.dtype)
-            q_ids = np.concatenate([q_ids, pad_i])
-            q_vals = np.concatenate([q_vals, pad_v])
-        mi, mv = kops.merge_queries(q_ids, q_vals)
-        # pad the merged stream to the bucket's fixed capacity
-        pad = self.bucket_Q(mi.size, Lp)
-        mi = np.pad(mi, (0, pad - mi.size), constant_values=-2)
-        mv = np.pad(mv, ((0, pad - mv.shape[0]), (0, 0)))
-        q_norms = np.sqrt((np.where(q_vals > 0, q_vals, 0) ** 2).sum(1))
-        q_norms = np.maximum(q_norms, 1e-12).astype(np.float32)
-        q = (jnp.asarray(mi), jnp.asarray(mv), jnp.asarray(q_norms))
-        if self.backend == "pallas_fused":
-            return ((self.f_tiles,) + q,
-                    {"block_query": self.tiling.query_tile(Lp)})
-        return (self.d_ids, self.d_vals, self.d_norms, self.d_docids) + q, {}
+    def prepare(self, q_ids: np.ndarray, q_vals: np.ndarray
+                ) -> PreparedQuery:
+        """q_ids/q_vals: [L, Qn] (pad < 0) -> the merged query that every
+        slab of a pass is scored against, built and uploaded once. L is
+        padded to its compile bucket (next power-of-two multiple of the
+        model-axis size — the paper's L query batch, bucketed so the
+        serving layer's variable batches reuse cached programs)."""
+        with stage(self.obs.registry, NULL_SPAN, "slab_prep"):
+            L_ = q_ids.shape[0]
+            Lp = self.bucket_L(L_)
+            if Lp != L_:
+                pad_i = np.full((Lp - L_, q_ids.shape[1]), -1, q_ids.dtype)
+                pad_v = np.zeros((Lp - L_, q_vals.shape[1]), q_vals.dtype)
+                q_ids = np.concatenate([q_ids, pad_i])
+                q_vals = np.concatenate([q_vals, pad_v])
+            mi, mv = kops.merge_queries(q_ids, q_vals)
+            # pad the merged stream to the bucket's fixed capacity
+            pad = self.bucket_Q(mi.size, Lp)
+            mi = np.pad(mi, (0, pad - mi.size), constant_values=-2)
+            mv = np.pad(mv, ((0, pad - mv.shape[0]), (0, 0)))
+            q_norms = np.sqrt((np.where(q_vals > 0, q_vals, 0) ** 2).sum(1))
+            q_norms = np.maximum(q_norms, 1e-12).astype(np.float32)
+            kwargs = ({"block_query": self.tiling.query_tile(Lp)}
+                      if self.backend == "pallas_fused" else {})
+            return PreparedQuery((jnp.asarray(mi), jnp.asarray(mv),
+                                  jnp.asarray(q_norms)), kwargs)
+
+    def _slab_args(self, slab: Optional[SlabLike]) -> tuple:
+        """The program's corpus arguments: a device slab's arrays, or the
+        resident corpus's when ``slab`` is None."""
+        if slab is None:
+            return ((self.f_tiles,) if self.backend == "pallas_fused" else
+                    (self.d_ids, self.d_vals, self.d_norms, self.d_docids))
+        if isinstance(slab, PackedSlab):
+            return (slab.tiles,)
+        return tuple(slab)
+
+    def dispatch(self, q: PreparedQuery,
+                 slab: Optional[SlabLike] = None) -> "PatternSearchEngine":
+        """Start the program on ``slab`` (the resident corpus when None)
+        and return at once: a view of this engine that holds the
+        program's device (vals, ids), not yet read. The view holds
+        neither the slab nor the query, so a streamed slab's device
+        memory is freed once its program has run. A view, and not the
+        bare arrays, so that ``_search_arrays`` stays the one place a
+        slab's host top-k is made."""
+        with stage(self.obs.registry, NULL_SPAN, "slab_dispatch"):
+            view = object.__new__(PatternSearchEngine)
+            view.__dict__.update(self.__dict__)
+            view._out = self._search_fn(*self._slab_args(slab), *q.args,
+                                        **q.kwargs)
+            return view
+
+    def collect(self, views, q_ids: np.ndarray,
+                q_vals: np.ndarray) -> List[SearchResult]:
+        """The host top-k of every view ``dispatch`` returned for the query
+        batch ``q_ids``/``q_vals``: one ``jax.device_get`` starts every
+        copy back before it waits on any, then each view's result is read
+        through ``_search_arrays``."""
+        with stage(self.obs.registry, NULL_SPAN, "slab_wait"):
+            outs = jax.device_get([w._out for w in views])
+        for w, out in zip(views, outs):
+            w._out = out
+        return [w._search_arrays(q_ids, q_vals) for w in views]
 
     def lower(self, q_ids: np.ndarray, q_vals: np.ndarray):
         """The ``jax.stages.Lowered`` program a ``[L, Qn]`` query batch
         runs against the resident corpus (``.compile().as_text()`` shows
         whether a Pallas kernel is in it as a ``tpu_custom_call``)."""
-        args, kwargs = self._program_args(q_ids, q_vals)
-        return self._search_fn.lower(*args, **kwargs)
+        q = self.prepare(q_ids, q_vals)
+        return self._search_fn.lower(*self._slab_args(None), *q.args,
+                                     **q.kwargs)
 
     def _search_arrays(self, q_ids: np.ndarray,
                        q_vals: np.ndarray) -> SearchResult:
-        """q_ids/q_vals: [L, Qn] (pad < 0) -> the [L, k] top-k."""
+        """q_ids/q_vals: [L, Qn] (pad < 0) -> the [L, k] top-k of one pass
+        over the resident corpus: prepare, dispatch, collect (DESIGN.md
+        §8.2). On a view ``dispatch`` returned, the program has already
+        been started: its (vals, ids) are read (``collect`` has already
+        brought them to the host) and cut to the batch's L rows."""
         L_ = q_ids.shape[0]
         if L_ == 0:
             # an empty batch has a well-defined answer, not a degenerate
             # program shape (bucket_L would still pad to tp, but the
             # [0, k] result needs no kernel at all)
             return self.empty_result(0)
-        # the host path of one pass, split into stages (DESIGN.md §8.2):
-        # build and upload the merged query, dispatch the program, then
-        # wait for the device and copy the top-k back
-        reg = self.obs.registry
-        with stage(reg, NULL_SPAN, "slab_prep"):
-            args, kwargs = self._program_args(q_ids, q_vals)
-        with stage(reg, NULL_SPAN, "slab_dispatch"):
-            v, i = self._search_fn(*args, **kwargs)
-        with stage(reg, NULL_SPAN, "slab_wait"):
-            v = np.asarray(v)[:L_]
-            # ids come from local_topk / the fused epilogue already
-            # masked by row validity; re-masking by isfinite here renamed
-            # real docs with non-finite fp32 scores to -1 (see
-            # core.topk.local_topk)
-            i = np.asarray(i)[:L_]
-        return SearchResult(doc_ids=i.astype(np.int64), scores=v)
+        if self._out is None:
+            view = self.dispatch(self.prepare(q_ids, q_vals))
+            return self.collect([view], q_ids, q_vals)[0]
+        v, i = self._out
+        # ids come from local_topk / the fused epilogue already masked by
+        # row validity; re-masking by isfinite here renamed real docs
+        # with non-finite fp32 scores to -1 (see core.topk.local_topk)
+        return SearchResult(doc_ids=np.asarray(i)[:L_].astype(np.int64),
+                            scores=np.asarray(v)[:L_])
 
     # ------------------------------------------------------------------
     def search_streaming(self, q_ids, q_vals,
                          corpus_slabs: Iterable[SlabLike]) -> SearchResult:
         """Score a lazily-consumed sequence of corpus slabs larger than
-        resident memory, merging top-k across slabs (DESIGN.md §2).
+        resident memory, merging top-k across slabs in their order
+        (DESIGN.md §2).
 
-        Each element may be a host ``Corpus`` (uploaded here, with the next
-        slab's async device_put overlapping the current slab's scoring) or
-        an already-resident ``DeviceSlab`` (e.g. from the storage tier's
+        Each element may be a host ``Corpus`` (uploaded here) or an
+        already-resident ``DeviceSlab`` (e.g. from the storage tier's
         background prefetcher, which overlaps disk read + decode + upload
-        as well — DESIGN.md §3). The iterable is never materialized, so
+        as well — DESIGN.md §3). The query is prepared once, each slab's
+        program is dispatched as the slab arrives, and every top-k comes
+        back in one copy. The iterable is never materialized, so
         store-backed iterators stream arbitrarily large corpora."""
-        best: Optional[SearchResult] = None
         it = iter(corpus_slabs)
-        cur = self._as_device(next(it, None))
-        if cur is None:
+        first = next(it, None)
+        if first is None:
             return self.empty_result(q_ids.shape[0])
-        while cur is not None:
-            # start the next H2D transfer before scoring the current slab
-            nxt = self._as_device(next(it, None))
-            r = eng_search(self._with_slab(cur), q_ids, q_vals)
+        q = self.prepare(q_ids, q_vals)
+        views = [self.dispatch(q, self._as_device(s))
+                 for s in itertools.chain([first], it)]
+        best: Optional[SearchResult] = None
+        for r in self.collect(views, q_ids, q_vals):
             best = r if best is None else _merge_results(best, r,
                                                          self.cfg.top_k)
-            cur = nxt
         return best
 
     @property
@@ -385,15 +439,6 @@ class PatternSearchEngine:
             return slab
         return self.put_slab(slab)
 
-    def _with_slab(self, dev: SlabLike):
-        eng = object.__new__(PatternSearchEngine)
-        eng.__dict__.update(self.__dict__)
-        if isinstance(dev, PackedSlab):
-            eng.f_tiles = dev.tiles
-        else:
-            eng.d_ids, eng.d_vals, eng.d_norms, eng.d_docids = dev
-        return eng
-
 
 def slab_program(cfg: SearchConfig, ctx: MeshCtx, backend: str,
                  on_trace=None):
@@ -437,12 +482,6 @@ def slab_program(cfg: SearchConfig, ctx: MeshCtx, backend: str,
         return f(ids, vals, norms, docids, q_ids, q_vals, q_norms)
 
     return search
-
-
-def eng_search(eng: PatternSearchEngine, q_ids, q_vals) -> SearchResult:
-    # the streaming hot loop's internal entry: positional arrays without
-    # the public shim's deprecation machinery
-    return PatternSearchEngine._search_arrays(eng, q_ids, q_vals)
 
 
 def _merge_results(a: SearchResult, b: SearchResult, k: int) -> SearchResult:

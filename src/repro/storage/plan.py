@@ -18,11 +18,13 @@ when the view is a live snapshot, and the padded program shape. Steps
 are ordered cache-first so the prefetcher thread overlaps every disk
 decode behind the free cache hits.
 
-The executor is the only scan loop in the tree: it streams the plan's
-steps through the §3.3 Prefetcher, scores each slab as it lands, and
-folds the per-slab candidates in *manifest rank order* (memtable last)
-so the scan-order optimization can never change score-tie breaking
-relative to a cold scan. The cache is consulted at *execution* time (a
+The executor is the only scan loop in the tree: it prepares the query
+once, streams the plan's steps through the §3.3 Prefetcher, dispatches
+each slab's program as the slab lands without waiting for it, brings
+every slab's top-k back in one copy, and then folds the per-slab
+candidates in *manifest rank order* (memtable last) so the scan-order
+optimization can never change score-tie breaking relative to a cold
+scan. The cache is consulted at *execution* time (a
 planned hit that was evicted in between simply degrades to a disk load
 — plans are advisory about sources, never about correctness), and one
 ``SearchStats`` is filled, including the cache hit/miss/eviction
@@ -219,21 +221,24 @@ def execute_plan(engine, view, plan: QueryPlan, q_ids: np.ndarray,
     ``stats`` (a SearchStats) as slabs resolve. The shared scan loop
     behind every scoring surface (DESIGN.md §4.1).
 
-    Slabs are *scored* in the plan's cache-first scan order (so the
-    prefetcher overlaps disk decodes behind the free hits) but their
-    per-slab candidates are *folded* in manifest rank order, memtable
-    last — exactly the cold scan's fold. ``_merge_results`` breaks
-    score ties by fold position, so without the rank fold a partially
-    warm query could flip tied candidates relative to a cold one.
+    Slabs are *dispatched* in the plan's cache-first scan order (so the
+    prefetcher overlaps disk decodes behind the free hits) and the host
+    never waits on the device between them: the engine's ``prepare``
+    runs once before the loop, ``dispatch`` once per slab, and
+    ``collect`` once after it. The per-slab candidates are then
+    *folded* in manifest rank order, memtable last — exactly the cold
+    scan's fold. ``_merge_results`` breaks score ties by fold position,
+    so without the rank fold a partially warm query could flip tied
+    candidates relative to a cold one.
 
     ``span``/``registry`` are the §8 observability hooks: per-segment
     child spans (slab source, decode/upload ms) hang off ``span`` when
     a trace sampled this query (``NULL_SPAN`` otherwise — allocation-
-    free), and each stage (decode, upload, score, merge) runs under
-    ``obs.stage``: a ``repro.<stage>`` profiler annotation plus its
-    ``stage_ms`` histogram. Neither touches the numeric path: scan
-    order, fold order, and every array op are identical with
-    observability on, off, or disabled."""
+    free), and each stage (decode, upload, score — one slab's dispatch —
+    and merge) runs under ``obs.stage``: a ``repro.<stage>`` profiler
+    annotation plus its ``stage_ms`` histogram. Neither touches the
+    numeric path: scan order, fold order, and every array op are
+    identical with observability on, off, or disabled."""
     reg = NULL_REGISTRY if registry is None else registry
     # the Obs.disabled() floor (§8.1): with a null registry AND no trace
     # span every stage is the shared no-op and the prefetcher skips its
@@ -326,8 +331,9 @@ def execute_plan(engine, view, plan: QueryPlan, q_ids: np.ndarray,
     if plan.is_empty:
         span.set(empty=True)
         return engine.empty_result(q_ids.shape[0])
-    # one fold slot per scored segment in manifest order, + the memtable
-    folds: List[Optional[object]] = [None] * (len(plan.steps) + 1)
+    # one slot per scored segment in manifest order, + the memtable: the
+    # view ``engine.dispatch`` returned for it
+    started: List[Optional[object]] = [None] * (len(plan.steps) + 1)
     mem_slab = None
     if plan.memtable is not None:
         # stats land BEFORE the prefetcher (and its loader thread)
@@ -340,30 +346,24 @@ def execute_plan(engine, view, plan: QueryPlan, q_ids: np.ndarray,
                     timed=timed) \
         if plan.steps else None
     try:
+        # the merged query is built and uploaded once for the whole pass
+        q = engine.prepare(q_ids, q_vals)
         if mem_slab is not None:
-            # scored while the prefetcher's worker loads the first slabs
+            # dispatched while the prefetcher's worker loads the first slabs
             with stage(reg, span, "score", segment="memtable") as mst:
-                folds[-1] = engine.search_streaming(q_ids, q_vals,
-                                                    [mem_slab])
+                started[-1] = engine.dispatch(q, engine.put_slab(mem_slab))
             mst.span.set(source="memtable", docs=stats.memtable_docs)
         if pf is not None:
             for step, slab in pf:
                 if slab is None:        # empty approx candidate pool
                     continue
+                # no wait for the device here: each slab's program is
+                # queued behind the last, and the loop's reference to the
+                # slab goes with the next one (JAX keeps a dispatched
+                # input alive until its program has run)
                 with stage(reg, span, "score", segment=step.name,
                            rank=step.rank):
-                    r = engine.search_streaming(q_ids, q_vals, [slab])
-                    folds[step.rank] = r
-                    # a segment the vocab filter let through whose every
-                    # real score is exactly 0 had no query-term overlap:
-                    # a filter false positive (exact for bitmaps, the
-                    # Bloom FPR made flesh) — surfaced per query so the
-                    # fleet can see when a filter has gone saturated
-                    if plan.filtered:
-                        sc = np.asarray(r.scores)
-                        fin = sc[np.isfinite(sc)]
-                        if fin.size == 0 or not np.any(fin != 0):
-                            stats.filter_fp_segments += 1
+                    started[step.rank] = engine.dispatch(q, slab)
     finally:
         if pf is not None:
             pf.close()
@@ -371,6 +371,21 @@ def execute_plan(engine, view, plan: QueryPlan, q_ids: np.ndarray,
         wait_ms = pf.consumer_wait_s * 1e3
         reg.histogram("stage_ms", stage="prefetch_wait").observe(wait_ms)
         span.set(prefetch_wait_ms=round(wait_ms, 3))
+    # every slab's top-k comes back in one copy
+    views = [v for v in started if v is not None]
+    results = iter(engine.collect(views, q_ids, q_vals))
+    folds = [None if v is None else next(results) for v in started]
+    if plan.filtered:
+        # a segment the vocab filter let through whose every real score
+        # is exactly 0 had no query-term overlap: a filter false positive
+        # (exact for bitmaps, the Bloom FPR made flesh) — surfaced per
+        # query so the fleet can see when a filter has gone saturated
+        for r in folds[:-1]:
+            if r is None:
+                continue
+            fin = r.scores[np.isfinite(r.scores)]
+            if fin.size == 0 or not np.any(fin != 0):
+                stats.filter_fp_segments += 1
     n_folds = sum(r is not None for r in folds)
     with stage(reg, span, "merge", folds=n_folds):
         best = None

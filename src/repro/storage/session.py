@@ -7,7 +7,8 @@ slices into accelerator kernels:
         -> Planner: filter verdicts + slab sources  (§4.1)
         -> execute_plan: SlabCache hits (§4.2) + Prefetcher disk
            decodes (§3.3), cache-first scan order
-        -> PatternSearchEngine.search_streaming (score + merge top-k)
+        -> PatternSearchEngine prepare / dispatch each slab / collect
+           once, then the rank-ordered top-k merge
 
 Every surviving segment becomes one fixed-shape DeviceSlab (padded to the
 store's largest segment rounded up to the mesh rows) so the whole stream

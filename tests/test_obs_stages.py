@@ -1,8 +1,9 @@
 """The program's own stage spans (DESIGN.md §8.2): every ``repro.*``
-stage lands on the profiler's timeline on the thread that ran it, the
-engine's per-pass stages nest inside the score stage and that inside the
-service's batch, and the per-pass ``stage_ms`` histograms split the score
-stage without changing an answer."""
+stage lands on the profiler's timeline on the thread that ran it, a
+slab's dispatch nests inside its score stage and that inside the
+service's batch, a pass's prepare and collect run once inside the batch
+and outside every score stage, and the engine's ``stage_ms`` histograms
+count them without changing an answer."""
 import glob
 import os
 import time
@@ -102,9 +103,13 @@ def test_stage_spans_on_the_profiler_timeline(store, tmp_path):
     for s in STAGES:
         if s not in ("decode", "upload"):
             assert {e[0] for e in by[s]} == {"search-service"}, s
-    for s in SLAB_STAGES:
-        assert all(_inside(e, by["score"]) for e in by[s]), s
-    for s in ("plan", "score", "prefetch_wait", "merge"):
+    # a pass prepares its query before the scan and collects every slab
+    # after it: only the dispatch runs inside a slab's score stage
+    assert all(_inside(e, by["score"]) for e in by["slab_dispatch"])
+    for s in ("slab_prep", "slab_wait"):
+        assert not any(_inside(e, by["score"]) for e in by[s]), s
+    for s in ("plan", "score", "prefetch_wait", "merge", "slab_prep",
+              "slab_wait"):
         assert all(_inside(e, by["batch"]) for e in by[s]), s
 
 
@@ -113,7 +118,9 @@ def test_slab_stages_split_the_score_stage(store):
     queries = [_query(corpus, d) for d in (3, 120, 260, 333, 7)]
     obs = Obs()
     on = _session(store, obs)
+    t0 = time.perf_counter()
     got = [on.search_typed(q) for q in queries]
+    passes_ms = (time.perf_counter() - t0) * 1e3
     on.close()
     off = _session(store, Obs.disabled())
     want = [off.search_typed(q) for q in queries]
@@ -125,6 +132,10 @@ def test_slab_stages_split_the_score_stage(store):
           for s in ("score", "decode") + SLAB_STAGES}
     assert st["score"].total == len(queries) * SEGMENTS
     assert st["decode"].total > 0     # the cache held part of the store
-    for s in SLAB_STAGES:
-        assert st[s].total == st["score"].total, s
-    assert sum(st[s].sum for s in SLAB_STAGES) < st["score"].sum
+    # one dispatch a slab, inside its score stage; one prepare and one
+    # collect a pass
+    assert st["slab_dispatch"].total == st["score"].total
+    assert st["slab_dispatch"].sum < st["score"].sum
+    for s in ("slab_prep", "slab_wait"):
+        assert st[s].total == len(queries), s
+    assert sum(st[s].sum for s in SLAB_STAGES) < passes_ms
