@@ -2,11 +2,19 @@
 plain reference, and the result line.
 
 The served path is the one a user calls, with every program setting at
-its default: ``FlashSearchSession.submit`` -> the ``SearchService``
-coalescer -> ``Planner`` / ``execute_plan`` (``SlabCache``, ``Prefetcher``,
-decode and upload) -> ``PatternSearchEngine.search_streaming`` -> the
-scoring program -> the top-k merge. The store is written through the
-program's own ``FlashStore.create`` / ``append_docs``.
+its default. A configuration names its ``surface`` (``store`` where it
+names none):
+
+- ``store``: ``FlashSearchSession.submit`` -> the ``SearchService``
+  coalescer -> ``Planner`` / ``execute_plan`` (``SlabCache``,
+  ``Prefetcher``, decode and upload) -> ``PatternSearchEngine`` ->
+  the scoring program -> the top-k merge, over one store written through
+  the program's own ``FlashStore.create`` / ``append_docs``;
+- ``cluster``: ``FlashClusterSession.submit`` -> the same coalescer ->
+  ``ShardRouter`` (scatter to one ``FlashSearchSession`` a shard replica,
+  each on its router-assigned device, over the cluster-shared slab
+  cache; gather and merge), over ``n_shards`` x ``replicas`` stores
+  written by the program's own ``build_sharded_store`` under ``policy``.
 
 Order of a run: generate the corpus from the seed; build the store; open
 the session; warm every L bucket (1, 2, 4, 8) and run warm-up queries
@@ -18,7 +26,6 @@ reduce it.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import shutil
@@ -55,6 +62,14 @@ def device_info(chips: int, require_tpu: bool = True) -> dict:
         raise NoDevice(f"the cell needs {chips} chips, JAX finds {len(devs)}")
     return {"platform": d0.platform, "kind": d0.device_kind,
             "count": len(devs)}
+
+
+def chip_memory(chips: int, key: str) -> List[Optional[int]]:
+    """``memory_stats()[key]`` of each of the first ``chips`` devices
+    (None where the backend keeps no such count)."""
+    import jax
+    return [(d.memory_stats() or {}).get(key)
+            for d in jax.devices()[:chips]]
 
 
 def compile_cache_env(root: str) -> str:
@@ -200,38 +215,6 @@ def delta(before: Dict[str, tuple], after: Dict[str, tuple]):
             for k, v in after.items()}
 
 
-@contextlib.contextmanager
-def layer_spans(session):
-    """Host spans around the calls into each layer, recorded into the
-    profiler's trace so idle device time can be put down to what the host
-    was doing: ``bench.batch`` (one coalesced batch through the session),
-    ``bench.plan``, ``bench.score`` (one slab through the engine) and
-    ``bench.upload`` (a slab loaded from the store and put on the device,
-    on the prefetch thread). Wraps the instances' bound methods; nothing
-    in the numeric path changes."""
-    import jax
-    wrapped = []
-
-    def wrap(obj, attr, name):
-        fn = getattr(obj, attr)
-
-        def traced(*a, **kw):
-            with jax.profiler.TraceAnnotation(name):
-                return fn(*a, **kw)
-        setattr(obj, attr, traced)
-        wrapped.append((obj, attr))
-
-    wrap(session, "search_typed", "bench.batch")
-    wrap(session._planner, "plan", "bench.plan")
-    wrap(session.engine, "search_streaming", "bench.score")
-    wrap(session.engine, "put_slab", "bench.upload")
-    try:
-        yield
-    finally:
-        for obj, attr in wrapped:
-            delattr(obj, attr)
-
-
 def _stack(bags):
     """[(ids, vals), ...] -> [L, Qn] rows, -1 / 0 padded."""
     qn = max(max(b[0].size for b in bags), 1)
@@ -253,6 +236,61 @@ def build_store(path: str, corpus, config: dict):
     return store
 
 
+def _written_at(path: str) -> float:
+    """The newest modification time of the files under ``path``."""
+    return max((os.path.getmtime(os.path.join(d, f))
+                for d, _, files in os.walk(path) for f in files),
+               default=0.0)
+
+
+def open_store(path: str, corpus, config: dict):
+    """Surface ``store``: one store, one ``FlashSearchSession``. Returns
+    the session, the segments one scan reads and a note for the log."""
+    from repro.storage import FlashSearchSession
+    store = build_store(path, corpus, config)
+    return (FlashSearchSession(store, search_config(config)), store.entries,
+            "")
+
+
+def open_cluster(path: str, corpus, config: dict):
+    """Surface ``cluster``: ``n_shards`` x ``replicas`` stores written by
+    ``build_sharded_store`` under ``policy``, served by one
+    ``FlashClusterSession``. A scan reads one replica of every shard, so
+    the segments are those of replica 0 of each. The note gives how long
+    the document list took, each replica's write (shard-major, the order
+    the program writes them; the first also holds the partitioning), read
+    from the files' modification times, and each replica's device."""
+    from repro.cluster import FlashClusterSession, build_sharded_store
+    shutil.rmtree(path, ignore_errors=True)
+    t0 = time.perf_counter()
+    docs = corpus.docs(0, corpus.n_docs)
+    t1, at = time.perf_counter(), time.time()
+    store = build_sharded_store(
+        path, docs=docs, n_shards=int(config["n_shards"]),
+        replicas=int(config["replicas"]), policy=config["policy"],
+        vocab_size=int(config["vocab_size"]),
+        docs_per_segment=int(config["docs_per_segment"]))
+    del docs
+    writes = []
+    for s, shard in enumerate(store.manifest["shards"]):
+        for r, rel in enumerate(shard["replicas"]):
+            done = _written_at(os.path.join(path, rel))
+            writes.append((f"{s}.{r}", round(done - at, 3)))
+            at = done
+    entries = [e for s in range(store.n_shards)
+               for e in store.store(s).entries]
+    session = FlashClusterSession(store, search_config(config))
+    placement = [(f"{s}.{r}", str(session.router.device_of(s, r)))
+                 for s in range(store.n_shards)
+                 for r in range(store.replicas)]
+    return session, entries, (
+        f"document list {t1 - t0:.3f}s | replica writes (shard.replica, s): "
+        f"{writes} | placement: {placement}")
+
+
+SURFACES = {"store": open_store, "cluster": open_cluster}
+
+
 def search_config(config: dict):
     from repro.configs.paper_search import SearchConfig
     return SearchConfig(name=config["name"],
@@ -262,19 +300,30 @@ def search_config(config: dict):
                         top_k=int(config["top_k"]))
 
 
-def warm_up(session, make, corpus, rng, max_passes: int = 8) -> List[tuple]:
+def slab_lookups(counts: Dict[str, tuple], surface: str) -> tuple:
+    """The slab cache's hits and misses in ``counts`` (a snapshot or a
+    delta) as the session of ``surface`` publishes them: a store session
+    under ``surface=store``; a cluster's router, summed over the shards,
+    under ``surface=cluster`` (its shard sessions publish none)."""
+    return tuple(counts.get(f"cache_{k}_total{{surface={surface}}}",
+                            (0,))[0] for k in ("hits", "misses"))
+
+
+def warm_up(session, make, corpus, rng, lookups, max_passes: int = 8
+            ) -> List[tuple]:
     """Compile every L bucket the coalescer can flush (1, 2, 4, 8), then
-    run batches until the slab cache's hits and misses per batch repeat;
-    last, two bursts through ``submit`` warm the coalescer itself."""
+    run batches until the slab cache's hits and misses per batch repeat
+    (``lookups()`` reads them); last, two bursts through ``submit`` warm
+    the coalescer itself."""
     from repro.serve import Query
     seen = []
 
     def batch(L):
         docs = rng.integers(0, corpus.n_docs, L)
+        before = lookups()
         session.search_typed(Query(*_stack([make(corpus, int(d))
                                             for d in docs])))
-        st = session.last_stats
-        seen.append((L, st.cache_hits, st.cache_misses))
+        seen.append((L,) + tuple(a - b for a, b in zip(lookups(), before)))
 
     for L in (1, 2, 4, 8):
         batch(L)
@@ -307,16 +356,17 @@ class Setup:
             self.cache_dir = enable_compile_cache()
         self.compiles = CompileCounter()
         from repro.obs import default_obs
-        from repro.storage import FlashSearchSession
         self.registry = default_obs().registry
         self.at_start = snapshot(self.registry)
         self.cell, self.seed = cell, seed
         config = cell.config
+        self.surface = config.get("surface", "store")
         self.gen = spec.part("generators", config["generator"])
         # the query maker the mix names, from the configuration's generator
         self.make = getattr(self.gen, cell.traffic["queries"])
         self.ref = spec.part("references", config["reference"])
         self.peaks = peaks_for(self.info["kind"]) if require_tpu else None
+        self.idle_bytes = chip_memory(cell.chips, "bytes_in_use")
         work = os.path.join(root, ".bench_work")
         os.makedirs(work, exist_ok=True)
         self.store_dir = os.path.join(work, "store")
@@ -325,19 +375,22 @@ class Setup:
         t0 = time.perf_counter()
         self.corpus = self.gen.generate(config, seed)
         t1 = time.perf_counter()
-        store = build_store(self.store_dir, self.corpus, config)
+        self.session, entries, note = SURFACES[self.surface](
+            self.store_dir, self.corpus, config)
         t2 = time.perf_counter()
-        entries = store.entries
         self.seg_bytes = 4.0 * sum(e.n_items for e in entries) / len(entries)
-        self.session = FlashSearchSession(store, search_config(config))
         seen = warm_up(self.session, self.make, self.corpus,
-                       np.random.default_rng([seed, 1]))
+                       np.random.default_rng([seed, 1]),
+                       lambda: slab_lookups(snapshot(self.registry),
+                                            self.surface))
         t3 = time.perf_counter()
         self.service = self.session.service()
         c = self.compiles
-        say(f"[setup] {self.corpus.n_docs} docs, {len(entries)} segments, "
+        say(f"[setup] {self.corpus.n_docs} docs, surface {self.surface}, "
+            f"{len(entries)} segments a scan, "
             f"{self.seg_bytes:.1f} stream bytes per segment | generate "
             f"{t1 - t0:.3f}s store {t2 - t1:.3f}s warm-up {t3 - t2:.3f}s | "
+            f"{note + ' | ' if note else ''}"
             f"warm-up batches (L, cache hits, misses): {seen} | programs "
             f"built {c.n} in {c.seconds:.3f}s, {c.loaded} of them loaded "
             f"from the persistent cache ({self.cache_dir})")
@@ -360,45 +413,43 @@ class Setup:
         prepared = arrivals.prepare(
             mix, seconds, rng, lambda n: selector.pick(n_docs, n, rng, mix),
             self.query)
-        spans = layer_spans(session) if trace else contextlib.nullcontext()
-        with spans:
-            if trace:
-                opts = jax.profiler.ProfileOptions()
-                opts.python_tracer_level = 0
-                jax.profiler.start_trace(self.trace_dir,
-                                         profiler_options=opts)
-            n_batches0 = service.stats.n_batches
-            n_req0 = service.stats.n_requests
-            before = snapshot(self.registry)
-            compiles0 = self.compiles.n
-            traces0 = session.compile_stats["n_traces"]
-            gcs = GcPauses()
-            win = jax.profiler.TraceAnnotation("bench.window")
-            setup_s = (time.perf_counter() - t_start
-                       if t_start is not None else None)
-            w0 = time.perf_counter()
-            t_end = w0 + seconds
-            watch = HostWatch(w0)
-            win.__enter__()
-            reqs = arrivals.drive(prepared, session.submit, w0, t_end,
-                                  batch_of)
-            time.sleep(max(0.0, t_end - time.perf_counter()))
-            w1 = time.perf_counter()
-            pending = service.pending_count
-            win.__exit__(None, None, None)
-            host = watch.close()
-            gcs.close()
-            after = snapshot(self.registry)
-            n_batches = service.stats.n_batches - n_batches0
-            n_req = service.stats.n_requests - n_req0
-            compiled = self.compiles.n - compiles0
-            retraced = session.compile_stats["n_traces"] - traces0
-            if trace:
-                jax.profiler.stop_trace()
-            deadline = time.perf_counter() + DRAIN_S
-            while (any(r.done is None for r in reqs)
-                   and time.perf_counter() < deadline):
-                time.sleep(0.05)
+        if trace:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir,
+                                     profiler_options=opts)
+        n_batches0 = service.stats.n_batches
+        n_req0 = service.stats.n_requests
+        before = snapshot(self.registry)
+        compiles0 = self.compiles.n
+        traces0 = session.compile_stats["n_traces"]
+        gcs = GcPauses()
+        win = jax.profiler.TraceAnnotation("bench.window")
+        setup_s = (time.perf_counter() - t_start
+                   if t_start is not None else None)
+        w0 = time.perf_counter()
+        t_end = w0 + seconds
+        watch = HostWatch(w0)
+        win.__enter__()
+        reqs = arrivals.drive(prepared, session.submit, w0, t_end,
+                              batch_of)
+        time.sleep(max(0.0, t_end - time.perf_counter()))
+        w1 = time.perf_counter()
+        pending = service.pending_count
+        win.__exit__(None, None, None)
+        host = watch.close()
+        gcs.close()
+        after = snapshot(self.registry)
+        n_batches = service.stats.n_batches - n_batches0
+        n_req = service.stats.n_requests - n_req0
+        compiled = self.compiles.n - compiles0
+        retraced = session.compile_stats["n_traces"] - traces0
+        if trace:
+            jax.profiler.stop_trace()
+        deadline = time.perf_counter() + DRAIN_S
+        while (any(r.done is None for r in reqs)
+               and time.perf_counter() < deadline):
+            time.sleep(0.05)
         ok = [r for r in reqs if r.done is not None and r.error is None]
         late = np.array([(r.sent - r.due) * 1e3 for r in reqs] or [0.0])
         worst = [(round(float(late[i]), 1), round(reqs[i].due - w0, 2))
@@ -407,6 +458,7 @@ class Setup:
         in_window = [r for r in ok if r.done <= t_end]
         d = delta(before, after)
         occ = n_req / n_batches if n_batches else 0.0
+        hits, misses = slab_lookups(d, self.surface)
         say(f"[window] {seconds:.3f}s measured ({w1 - w0:.3f}s), "
             f"{len(reqs)} attempted, {len(ok)} answered, {len(in_window)} "
             f"by the close, {pending} queued at the close | generator late "
@@ -418,10 +470,8 @@ class Setup:
             f"collections {gcs.full}, longest collection "
             f"{gcs.longest * 1e3:.1f} ms | "
             f"{n_batches} batches, mean occupancy {occ:.3f} | slab cache "
-            f"hits {d.get('cache_hits_total{surface=store}', (0,))[0]} "
-            f"misses {d.get('cache_misses_total{surface=store}', (0,))[0]} "
-            f"| programs built in the window {compiled}, scoring-program "
-            f"traces {retraced}")
+            f"hits {hits} misses {misses} | programs built in the window "
+            f"{compiled}, scoring-program traces {retraced}")
         lat = [r.latency_ms for r in ok]
         if lat:
             say(f"[latency] p50 {percentile(lat, 50):.3f} ms p95 "
@@ -433,11 +483,12 @@ class Setup:
                 "delta": d, "compiled": compiled}
 
     def close(self) -> Optional[int]:
-        """Read the peak device memory (the fullest chip's), then free the
-        session and the store. Returns the peak."""
-        import jax
-        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-                 for d in jax.devices()[:self.cell.chips]]
+        """Read the peak device memory of each chip the cell asks for,
+        then free the session and the store. Returns the fullest chip's
+        peak."""
+        peaks = chip_memory(self.cell.chips, "peak_bytes_in_use")
+        say(f"[memory] peak bytes a chip {peaks}, in use before set-up "
+            f"{self.idle_bytes}")
         self.run_delta = delta(self.at_start, snapshot(self.registry))
         self.compiles.close()
         self.session.close()
@@ -484,7 +535,7 @@ class Setup:
         checks["unanswered"] = {"value": len(reqs) - len(ok), "limit": 0}
         checks["pairs_truncated"] = {
             "value": self.run_delta.get(
-                "pairs_truncated_total{surface=store}", (0,))[0],
+                f"pairs_truncated_total{{surface={self.surface}}}", (0,))[0],
             "limit": 0}
         say(f"[check] {len(sample)} answers against the reference in "
             f"{time.perf_counter() - t0:.3f}s, from batches of sizes "
@@ -531,8 +582,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         red = trace_reduce.reduce_trace(path) if path else {}
         shutil.rmtree(s.trace_dir, ignore_errors=True)
         rec = {"batches": w["batches"], "requests": w["requests"],
-               "delta": w["delta"], "trace": red, "peaks": s.peaks,
-               "segment_stream_bytes": s.seg_bytes, "seconds": seconds}
+               "delta": w["delta"], "surface": s.surface, "trace": red,
+               "peaks": s.peaks, "segment_stream_bytes": s.seg_bytes,
+               "seconds": seconds}
         metrics = {}
         for m in cell.per_layer:
             v = spec.part("metrics", m["name"]).read(rec)

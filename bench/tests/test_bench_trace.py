@@ -1,5 +1,6 @@
-"""The trace reduction, on a short trace recorded on a TPU v5e from the
-shard32 cell (three seconds or less of the served path)."""
+"""The trace reduction, on a short trace recorded on a TPU v5e: one second
+of the served path of the shard32 configuration cut to 65,536 docs
+(``bench/sweep.py --keep-trace``), with the program's own stage spans."""
 import glob
 import os
 
@@ -30,7 +31,11 @@ def test_breakdown_lists(reduced):
     assert [s for _, s in ops] == sorted((s for _, s in ops), reverse=True)
     idle = reduced["window_s"] - reduced["busy_s"]
     assert sum(s for _, s in gaps) <= idle + 1e-6
-    assert any("score" in n for n, _ in gaps)
+    if len(gaps) < 10:      # every label listed: the split covers the idle
+        assert sum(s for _, s in gaps) == pytest.approx(idle)
+    assert all(part == "none" or part.startswith("repro.")
+               for n, _ in gaps for part in n.split("+"))
+    assert any(n == "repro.plan" for n, _ in gaps)
 
 
 def test_scoring_program_and_its_roofline(reduced):
@@ -53,10 +58,18 @@ def test_readers_find_nothing_without_a_trace():
 def test_union_and_gap_labels():
     u = trace_reduce._union(np.array([[0, 2], [1, 3], [5, 6], [5.5, 5.7]]))
     assert u.tolist() == [[0, 3], [5, 6]]
-    spans = {"bench.batch": np.array([[0.0, 10.0]]),
-             "bench.score": np.array([[3.5, 4.5], [8.0, 9.0]])}
-    labels = trace_reduce._covering(spans, np.array([4.0, 6.0, 11.0]))
-    assert labels == ["batch+score", "batch", "none"]
+    s = 1e9                 # trace times are in ns; the split gives seconds
+    threads = {
+        "search-service": {"repro.batch": [(0, 10 * s)],
+                           "repro.score": [(3.5 * s, 4.5 * s),
+                                           (8 * s, 9 * s)]},
+        "slab-prefetch": {"repro.decode": [(5 * s, 7 * s)]}}
+    gaps = np.array([[3, 6], [10.5, 11]]) * s
+    split = dict(trace_reduce.split_idle(gaps, threads, top=10))
+    assert split == pytest.approx({
+        "repro.batch": 1.0, "repro.score": 1.0,
+        "repro.batch+repro.decode": 1.0, "none": 0.5})
+    assert trace_reduce.split_idle(gaps, {}, top=10) == [["none", 3.5]]
 
 
 def test_op_names_cut_to_name_and_shape():
