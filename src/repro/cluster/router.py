@@ -55,7 +55,7 @@ from repro.cluster.store import ShardedStore
 from repro.configs.paper_search import SearchConfig
 from repro.core.engine import SearchResult, _merge_results
 from repro.distributed.meshctx import single_device_ctx
-from repro.obs import NULL_SPAN, Obs, default_obs
+from repro.obs import NULL_SPAN, Obs, default_obs, stage
 from repro.serve.api import (Query, QueryOptions, QueryStats, SearchResponse,
                              coerce_request, truncate_k)
 from repro.serve.hedging import HedgePolicy, SpawnExecutor, run_hedged
@@ -188,7 +188,6 @@ class ShardRouter:
                  backend: str = "jnp", use_filter: bool = True,
                  prefetch_depth: int = 2,
                  max_workers: Optional[int] = None,
-                 slab_cache: Optional[SlabCache] = None,
                  cache_bytes: Optional[int] = None,
                  obs: Optional[Obs] = None,
                  hedge_policy: Optional[HedgePolicy] = None,
@@ -215,17 +214,20 @@ class ShardRouter:
         self.approx_min_docs = approx_min_docs
         # one memo cache for the whole cluster: shard stores have
         # distinct cache tokens, so entries can never alias across
-        # shards, and the budget is shared like the slab cache's
+        # shards, and they share one host-memory budget
         self._memo = (MemoCache(memo_entries) if memo_entries > 0
                       else None)
         # one observability bundle for the whole cluster (DESIGN.md §8):
         # shard sessions share it, so their stage histograms aggregate,
         # while query-level accounting stays with the router
         self.obs = obs if obs is not None else default_obs()
-        # one device slab cache for the whole cluster (DESIGN.md §4.2):
-        # every shard-replica session shares the byte budget, so a hot
-        # shard can hold more resident slabs than a cold one
-        self.slab_cache = SlabCache.resolve(slab_cache, cache_bytes)
+        # one device slab cache per chip (DESIGN.md §4.2), each sized by
+        # cache_bytes: the shard-replica sessions placed on a chip share
+        # its budget, as they share its memory (a failover replica opened
+        # on a chip draws on that chip's cache)
+        self.slab_caches: Dict[object, SlabCache] = (
+            {} if cache_bytes == 0 else
+            {d: SlabCache.resolve(None, cache_bytes) for d in self.devices})
         n, r = store.n_shards, store.replicas
         self._sessions: List[List[Optional[FlashSearchSession]]] = \
             [[None] * r for _ in range(n)]
@@ -300,13 +302,15 @@ class ShardRouter:
     def _session(self, shard: int, replica: int) -> FlashSearchSession:
         with self._lock:
             if self._sessions[shard][replica] is None:
+                dev = self.device_of(shard, replica)
+                cache = self.slab_caches.get(dev)
                 sess = FlashSearchSession(
                     self.store.store(shard, replica), self.cfg,
-                    ctx=single_device_ctx(self.device_of(shard, replica)),
+                    ctx=single_device_ctx(dev),
                     backend=self.backend, use_filter=self.use_filter,
                     prefetch_depth=self.prefetch_depth,
-                    slab_cache=self.slab_cache,
-                    cache_bytes=None if self.slab_cache is not None else 0,
+                    slab_cache=cache,
+                    cache_bytes=None if cache is not None else 0,
                     obs=self.obs, mode=self.mode,
                     candidates=self.candidates,
                     approx_min_docs=(self.approx_min_docs
@@ -481,81 +485,86 @@ class ShardRouter:
         the cluster — the raised ``ClusterSearchError`` carries the
         shard id, per-replica error summaries, and the trace id.
 
-        ``span`` is this shard's child of the cluster trace; each
-        replica attempt nests one level deeper, so fail-overs and
-        hedges show up as sibling replica spans (failed ones attr'd
-        with their error). Returns (result, stats, wall_ms,
+        The whole search runs under the ``repro.shard`` stage (one
+        ``stage_ms{stage=shard}`` observation a shard a batch), whose
+        sampled span is this shard's child of the cluster trace root
+        ``span``; each replica attempt nests one level deeper, so
+        fail-overs and hedges show up as sibling replica spans (failed
+        ones attr'd with their error). Returns (result, stats, wall_ms,
         hedges_fired, hedge_won)."""
-        t0 = time.perf_counter()
-        reps = [r for r in range(self.store.replicas)
-                if not self._down[shard][r]]
-        try:
-            if not reps:
-                raise ClusterSearchError(
-                    f"shard {shard}: no replica in rotation",
-                    shard=shard, trace_id=trace_id)
-            errs: Dict[int, BaseException] = {}
-            fired = won = 0
-            if hedge_after_s is not None and len(reps) > 1:
-                def make(rep: int):
-                    def attempt():
-                        try:
-                            return self._attempt(shard, rep, query, span,
-                                                 scoring_opts)
-                        except BaseException as e:
-                            errs[rep] = e
-                            raise
-                    return attempt
+        with stage(self.obs.registry, span, "shard", shard=shard) as sh:
+            span = sh.span
+            t0 = time.perf_counter()
+            reps = [r for r in range(self.store.replicas)
+                    if not self._down[shard][r]]
+            try:
+                if not reps:
+                    raise ClusterSearchError(
+                        f"shard {shard}: no replica in rotation",
+                        shard=shard, trace_id=trace_id)
+                errs: Dict[int, BaseException] = {}
+                fired = won = 0
+                if hedge_after_s is not None and len(reps) > 1:
+                    def make(rep: int):
+                        def attempt():
+                            try:
+                                return self._attempt(shard, rep, query, span,
+                                                     scoring_opts)
+                            except BaseException as e:
+                                errs[rep] = e
+                                raise
+                        return attempt
 
-                try:
-                    out = run_hedged(
-                        [make(r) for r in reps], self._hedge_executor(),
-                        hedge_after_s=hedge_after_s,
-                        on_hedge=lambda i: log.debug(
-                            "shard %d: hedging to replica %d", shard,
-                            reps[i]))
-                except ClusterSearchError:
-                    raise
-                except BaseException as e:
-                    raise ClusterSearchError(
-                        f"shard {shard}: all {len(reps)} in-rotation "
-                        f"replicas failed",
-                        shard=shard, trace_id=trace_id,
-                        replica_errors={r: repr(x)
-                                        for r, x in errs.items()}) from e
-                res, st, rep = out.result
-                fired, won = out.hedges_fired, int(out.hedge_won)
-            else:
-                res = None
-                for rep in reps:
                     try:
-                        res, st, _ = self._attempt(shard, rep, query, span,
-                                                   scoring_opts)
-                        break
-                    except Exception as e:
-                        errs[rep] = e
-                        log.warning(
-                            "shard %d replica %d failed (%s); failing over",
-                            shard, rep, e)
-                if res is None:
-                    raise ClusterSearchError(
-                        f"shard {shard}: all {len(reps)} in-rotation "
-                        f"replicas failed",
-                        shard=shard, trace_id=trace_id,
-                        replica_errors={r: repr(x) for r, x in errs.items()}
-                    ) from (errs[reps[-1]] if reps[-1] in errs else None)
-            # the winner proves the query is serveable: errored siblings
-            # (fail-overs in either path) leave rotation
-            for r in errs:
-                if r != rep:
-                    self.mark_down(shard, r)
-            wall_ms = (time.perf_counter() - t0) * 1e3
-            span.end(replica=rep, wall_ms=round(wall_ms, 3),
-                     **({"hedges": fired} if fired else {}))
-            return res, st, wall_ms, fired, won
-        except BaseException as e:
-            span.end(error=repr(e))
-            raise
+                        out = run_hedged(
+                            [make(r) for r in reps], self._hedge_executor(),
+                            hedge_after_s=hedge_after_s,
+                            on_hedge=lambda i: log.debug(
+                                "shard %d: hedging to replica %d", shard,
+                                reps[i]))
+                    except ClusterSearchError:
+                        raise
+                    except BaseException as e:
+                        raise ClusterSearchError(
+                            f"shard {shard}: all {len(reps)} in-rotation "
+                            f"replicas failed",
+                            shard=shard, trace_id=trace_id,
+                            replica_errors={r: repr(x)
+                                            for r, x in errs.items()}) from e
+                    res, st, rep = out.result
+                    fired, won = out.hedges_fired, int(out.hedge_won)
+                else:
+                    res = None
+                    for rep in reps:
+                        try:
+                            res, st, _ = self._attempt(shard, rep, query,
+                                                       span, scoring_opts)
+                            break
+                        except Exception as e:
+                            errs[rep] = e
+                            log.warning(
+                                "shard %d replica %d failed (%s); failing "
+                                "over", shard, rep, e)
+                    if res is None:
+                        raise ClusterSearchError(
+                            f"shard {shard}: all {len(reps)} in-rotation "
+                            f"replicas failed",
+                            shard=shard, trace_id=trace_id,
+                            replica_errors={r: repr(x)
+                                            for r, x in errs.items()}
+                        ) from (errs[reps[-1]] if reps[-1] in errs else None)
+                # the winner proves the query is serveable: errored
+                # siblings (fail-overs in either path) leave rotation
+                for r in errs:
+                    if r != rep:
+                        self.mark_down(shard, r)
+                wall_ms = (time.perf_counter() - t0) * 1e3
+                span.set(replica=rep, wall_ms=round(wall_ms, 3),
+                         **({"hedges": fired} if fired else {}))
+                return res, st, wall_ms, fired, won
+            except BaseException as e:
+                span.set(error=repr(e))
+                raise
 
     def search_typed(self, query: Query,
                      options: Optional[QueryOptions] = None, *,
@@ -609,62 +618,63 @@ class ShardRouter:
         walls: List[Optional[float]] = [None] * n
         missing: List[int] = []
         try:
-            shard_spans = [root.child("shard", shard=s) for s in range(n)]
-            futs = [self._pool.submit(self._search_shard, s, query,
-                                      shard_spans[s], hedge_after_s,
-                                      trace_id, scoring_opts)
-                    for s in range(n)]
-            # the gather span covers waiting out the stragglers plus the
-            # shard-order fold — the scatter itself lives in the shard
-            # children above
-            gspan = root.child("gather")
-            partial_ok = opts.allow_partial and deadline is not None
-            if partial_ok:
-                # one bounded wait for the whole scatter; anything not
-                # done at the budget is abandoned (it keeps running on
-                # the pool — the per-replica locks serialize it against
-                # the next query — but contributes nothing here)
-                wait(futs, timeout=max(0.0, deadline - time.perf_counter()))
-            best: Optional[SearchResult] = None
-            err: Optional[BaseException] = None
-            for s, fut in enumerate(futs):
-                if partial_ok and not fut.done():
-                    missing.append(s)
-                    shard_spans[s].end(abandoned=True)
-                    continue
-                try:
-                    # without partial consent this blocks for the shard:
-                    # the legacy full-gather contract
-                    res, st, wall_ms, fired, won = fut.result()
-                except BaseException as e:
-                    if opts.allow_partial:
-                        # degraded, not failed: SpANNS-style flagged
-                        # partial answer — the caller consented
+            # the gather stage runs from the scatter's submission to the
+            # merged answer: the wait on the slowest shard plus the
+            # shard-order fold; each shard's own search is its
+            # ``repro.shard`` stage on its pool thread
+            with stage(reg, root, "gather") as gather:
+                futs = [self._pool.submit(self._search_shard, s, query,
+                                          root, hedge_after_s, trace_id,
+                                          scoring_opts)
+                        for s in range(n)]
+                partial_ok = opts.allow_partial and deadline is not None
+                if partial_ok:
+                    # one bounded wait for the whole scatter; anything not
+                    # done at the budget is abandoned (it keeps running on
+                    # the pool — the per-replica locks serialize it
+                    # against the next query — but contributes nothing)
+                    wait(futs,
+                         timeout=max(0.0, deadline - time.perf_counter()))
+                best: Optional[SearchResult] = None
+                err: Optional[BaseException] = None
+                for s, fut in enumerate(futs):
+                    if partial_ok and not fut.done():
                         missing.append(s)
                         continue
-                    err = err or e
-                    continue
-                stats.hedges += fired
-                stats.hedge_wins += won
-                walls[s] = wall_ms
-                h_shard.observe(wall_ms)
-                # per-shard series feed the per-shard latency SLOs
-                # (§8.4) and make a straggling shard visible in /metrics
-                # without joining against the trace attrs
-                reg.histogram("cluster_shard_ms", shard=str(s)).observe(
-                    wall_ms)
-                stats.per_shard[s] = st
-                best = res if best is None else _merge_results(
-                    best, res, self.cfg.top_k)
-            done = [s for s, w in enumerate(walls) if w is not None]
-            if done:
-                straggler = max(done, key=lambda s: walls[s])
-                reg.histogram("cluster_straggler_ms").observe(
-                    walls[straggler])
-                root.set(straggler_shard=straggler,
-                         straggler_ms=round(walls[straggler], 3))
-            gspan.end(shards_merged=len(done),
-                      **({"shards_missing": missing} if missing else {}))
+                    try:
+                        # without partial consent this blocks for the
+                        # shard: the legacy full-gather contract
+                        res, st, wall_ms, fired, won = fut.result()
+                    except BaseException as e:
+                        if opts.allow_partial:
+                            # degraded, not failed: SpANNS-style flagged
+                            # partial answer — the caller consented
+                            missing.append(s)
+                            continue
+                        err = err or e
+                        continue
+                    stats.hedges += fired
+                    stats.hedge_wins += won
+                    walls[s] = wall_ms
+                    h_shard.observe(wall_ms)
+                    # per-shard series feed the per-shard latency SLOs
+                    # (§8.4) and make a straggling shard visible in
+                    # /metrics without joining against the trace attrs
+                    reg.histogram("cluster_shard_ms", shard=str(s)).observe(
+                        wall_ms)
+                    stats.per_shard[s] = st
+                    best = res if best is None else _merge_results(
+                        best, res, self.cfg.top_k)
+                done = [s for s, w in enumerate(walls) if w is not None]
+                if done:
+                    straggler = max(done, key=lambda s: walls[s])
+                    reg.histogram("cluster_straggler_ms").observe(
+                        walls[straggler])
+                    root.set(straggler_shard=straggler,
+                             straggler_ms=round(walls[straggler], 3))
+                gather.span.set(
+                    shards_merged=len(done),
+                    **({"shards_missing": missing} if missing else {}))
         finally:
             if trace is not None:
                 trace.finish()
@@ -734,12 +744,15 @@ class ShardRouter:
 
     @property
     def cache_stats(self) -> Optional[CacheStats]:
-        """Locked snapshot of the cluster-shared slab cache's lifetime
-        counters, or None when the cache is disabled. Shard sessions
-        mutate the counters concurrently under the cache lock, so the
-        lock-free live object could pair mid-flight hits/misses."""
-        return (self.slab_cache.stats_snapshot()
-                if self.slab_cache is not None else None)
+        """The lifetime counters of the per-chip slab caches, summed, or
+        None when caching is disabled. Each is a locked snapshot: shard
+        sessions mutate the counters concurrently under the cache lock,
+        so the lock-free live object could pair mid-flight hits/misses."""
+        if not self.slab_caches:
+            return None
+        snaps = [c.stats_snapshot() for c in self.slab_caches.values()]
+        return CacheStats(**{f.name: sum(getattr(s, f.name) for s in snaps)
+                             for f in dataclasses.fields(CacheStats)})
 
     @property
     def memo_stats(self):

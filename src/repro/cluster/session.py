@@ -32,11 +32,11 @@ class FlashClusterSession(ServingSessionMixin):
                  mode: str = "exact", candidates: int = 0,
                  approx_min_docs: Optional[int] = None,
                  memo_entries: int = 0):
-        """``cache_bytes`` sizes the cluster-shared device slab cache
-        (DESIGN.md §4.2) every shard-replica session draws on
-        (None = default budget, 0 = disabled). ``obs`` shares one
-        observability bundle (DESIGN.md §8) across the router and every
-        shard session; None falls back to the process default.
+        """``cache_bytes`` sizes each chip's device slab cache
+        (DESIGN.md §4.2), which the shard-replica sessions placed on that
+        chip draw on (None = default budget, 0 = disabled). ``obs``
+        shares one observability bundle (DESIGN.md §8) across the router
+        and every shard session; None falls back to the process default.
         ``hedge_policy`` arms replica hedging as the router default
         (DESIGN.md §7.3); per-query ``QueryOptions.hedging``
         overrides. ``mode``/``candidates``/``approx_min_docs`` set the
@@ -119,13 +119,14 @@ class FlashClusterSession(ServingSessionMixin):
         return self.router.last_trace
 
     @property
-    def slab_cache(self):
-        """The cluster-shared device slab cache (None when disabled)."""
-        return self.router.slab_cache
+    def slab_caches(self):
+        """The device slab cache of each chip, by device (empty when
+        caching is disabled)."""
+        return self.router.slab_caches
 
     @property
     def cache_stats(self):
-        """Lifetime slab-cache counters across every shard session —
+        """Lifetime slab-cache counters summed over the chips' caches —
         the same surface ``FlashSearchSession.cache_stats`` exposes."""
         return self.router.cache_stats
 

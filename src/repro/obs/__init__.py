@@ -29,7 +29,7 @@ import math
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, Mapping, Optional
 
 from .metrics import (DEFAULT_MS_BUCKETS, Counter, Gauge, Histogram,
                       MetricsRegistry, NULL_METRIC, NULL_REGISTRY)
@@ -117,17 +117,25 @@ class Obs:
 
     def publish_cache(self, cache) -> None:
         """Snapshot a SlabCache's lifetime state into gauges (export
-        time only — the cache keeps its own counters)."""
+        time only — the cache keeps its own counters). A cluster's
+        per-chip caches (a mapping of device to SlabCache) publish one
+        series each, under a ``device`` label."""
         if not self.enabled or cache is None:
             return
+        if isinstance(cache, Mapping):
+            for dev, c in cache.items():
+                self._publish_one(c, device=str(dev.id))
+        else:
+            self._publish_one(cache)
+
+    def _publish_one(self, cache, **labels) -> None:
         reg = self.registry
-        reg.gauge("slab_cache_bytes").set(cache.nbytes)
-        reg.gauge("slab_cache_entries").set(len(cache))
+        reg.gauge("slab_cache_bytes", **labels).set(cache.nbytes)
+        reg.gauge("slab_cache_entries", **labels).set(len(cache))
         st = cache.stats_snapshot()
-        reg.gauge("slab_cache_hits_lifetime").set(st.hits)
-        reg.gauge("slab_cache_misses_lifetime").set(st.misses)
-        reg.gauge("slab_cache_evictions_lifetime").set(st.evictions)
-        reg.gauge("slab_cache_invalidations_lifetime").set(st.invalidations)
+        for field in ("hits", "misses", "evictions", "invalidations"):
+            reg.gauge(f"slab_cache_{field}_lifetime", **labels).set(
+                getattr(st, field))
 
 
 _DEFAULT_LOCK = threading.Lock()

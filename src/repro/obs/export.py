@@ -145,13 +145,17 @@ def render_summary(searcher, obs: Optional[Obs] = None,
                 f"{name}[{_fmt_labels(labels)}]: n={m.count} "
                 f"p50={m.p50:.3f}ms p95={m.p95:.3f}ms p99={m.p99:.3f}ms")
 
-    # slab cache: every tier exposes the same cache_stats surface
+    # slab cache: every tier exposes the same cache_stats surface; a
+    # cluster holds one cache a chip
+    caches = getattr(searcher, "slab_caches", None)
     cache = getattr(searcher, "slab_cache", None)
     cstats = getattr(searcher, "cache_stats", None)
     if cstats is not None:
-        obs.publish_cache(cache)
-        extra = (f" bytes={cache.nbytes} entries={len(cache)}"
-                 if cache is not None else "")
+        obs.publish_cache(caches if caches is not None else cache)
+        held = (list(caches.values()) if caches is not None
+                else [cache] if cache is not None else [])
+        extra = (f" bytes={sum(c.nbytes for c in held)} "
+                 f"entries={sum(len(c) for c in held)}" if held else "")
         lines.append(
             f"slab cache: hit_rate={cstats.hit_rate:.3f} "
             f"hits={cstats.hits} misses={cstats.misses} "

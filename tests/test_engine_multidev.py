@@ -102,11 +102,12 @@ for backend in ("jnp", "pallas_fused"):
                 arrays_ok &= mesh_devs == {dev}
                 owner[sess_sr.store.cache_token] = dev
         n_slabs = 0
-        for key in router.slab_cache.keys():
-            entry = router.slab_cache.get(key)
-            for a in entry.slab:
-                n_slabs += 1
-                arrays_ok &= a.devices() == {owner[key[0]]}
+        for dev, cache in router.slab_caches.items():
+            for key in cache.keys():
+                arrays_ok &= owner[key[0]] == dev
+                for a in cache.get(key).slab:
+                    n_slabs += 1
+                    arrays_ok &= a.devices() == {dev}
     out[backend] = {
         "same": bool(np.array_equal(got.doc_ids, want.doc_ids)
                      and np.array_equal(got.scores, want.scores)),

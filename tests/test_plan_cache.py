@@ -167,10 +167,16 @@ def test_warm_equals_cold_cluster(tmp_path, corpus):
         assert cs.cache_stats.hits >= agg.cache_hits
         np.testing.assert_array_equal(cold.doc_ids, warm.doc_ids)
         np.testing.assert_array_equal(cold.scores, warm.scores)
-        # all shard sessions share ONE cache instance + byte budget
-        shard_sessions = cs.router._open_sessions()
+        # each shard session draws on its device's cache; on the one
+        # CPU device that is ONE cache instance + byte budget for all
+        router = cs.router
+        shard_sessions = router._open_sessions()
         assert len(shard_sessions) == 3
-        assert all(s.slab_cache is cs.slab_cache for s in shard_sessions)
+        assert all(router._sessions[s][0].slab_cache
+                   is cs.slab_caches[router.device_of(s, 0)]
+                   for s in range(3))
+        assert len(cs.slab_caches) == 1
+        assert len({id(s.slab_cache) for s in shard_sessions}) == 1
 
 
 def test_warm_equals_cold_service_submit(tmp_path, corpus):
